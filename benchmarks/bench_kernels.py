@@ -76,33 +76,29 @@ def run_vmem_report(fast: bool = False) -> dict:
     """Per-step VMEM bytes of the sparse-path kernels, before/after HBM
     residency.
 
-    ``before`` is what the legacy kernels held resident per grid step (the
-    whole CSR / index arrays as input blocks — O(nnz)); ``after`` is the
-    DMA-gather layout (frontier tiles + gather scratch, O(q_tile * K *
-    degree_cap) — independent of n and nnz).  Analytic from the block
-    shapes (exact: the buffers are fixed width), so the report also covers
-    pod-scale configs this container cannot allocate.  The 16 MB line is
-    the per-core VMEM budget the compiled (interpret=False) kernels must
-    fit; the ``hub`` point deliberately shows a config whose gather scratch
-    still overflows it — degree truncation / smaller q_tile remains the
-    operator's knob there even with HBM residency.
+    ``before`` is what a kernel holding the whole CSR / index arrays as
+    input blocks would need per grid step (O(nnz)); ``after`` is the
+    DMA-gather layout (output block + in-flight tiles, capped per step —
+    independent of n and nnz).  Analytic from the block shapes (exact: the
+    buffers are fixed width), so the report also covers configs too large
+    to allocate here.  The 16 MB line is the scoped VMEM budget the
+    compiled (interpret=False) kernels must fit.
     """
     vmem_budget = 16 * 1024 * 1024
-    # (label, n, m, q_tile, K, k_out, degree_cap, hub_split)
-    points = [("tiny", 4_096, 32_768, 8, 256, 200, 64, 0)]
+    # (label, n, m, q_tile, K, degree_cap, hub_split)
+    points = [("tiny", 4_096, 32_768, 8, 256, 64, 0)]
     if not fast:
         points += [
-            ("wiki", 100_000, 1_000_000, 8, 512, 200, 48, 0),
-            ("hub", 1_000_000, 16_000_000, 1, 512, 200, 16_384, 128),
+            ("wiki", 100_000, 1_000_000, 8, 512, 48, 0),
+            ("hub", 1_000_000, 16_000_000, 1, 512, 16_384, 128),
         ]
     out = {}
-    for label, n, m, q_tile, k, k_out, cap, split in points:
+    for label, n, m, q_tile, k, cap, split in points:
         after = push_mod.vmem_bytes(
-            q_tile, k, k_out, degree_cap=cap, hub_split_degree=split
+            q_tile, k, degree_cap=cap, hub_split_degree=split
         )
         before = push_mod.vmem_bytes_legacy(
-            q_tile, k, k_out, n=n, m=m, degree_cap=cap,
-            hub_split_degree=split,
+            q_tile, k, n=n, m=m, degree_cap=cap, hub_split_degree=split,
         )
         out[("push_vmem", label)] = dict(before=before, after=after)
         emit(
@@ -113,10 +109,8 @@ def run_vmem_report(fast: bool = False) -> dict:
             f"fits_16MB={'yes' if after <= vmem_budget else 'NO'}",
         )
         l = 32
-        c_after = comb_mod.sparse_vmem_bytes(q_tile, k, k, l, k_out)
-        c_before = comb_mod.sparse_vmem_bytes_legacy(
-            q_tile, k, k, l, k_out, n=n
-        )
+        c_after = comb_mod.sparse_vmem_bytes(q_tile, k, l)
+        c_before = comb_mod.sparse_vmem_bytes_legacy(q_tile, k, l, n=n)
         out[("combine_vmem", label)] = dict(before=c_before, after=c_after)
         emit(
             f"kernel_combine_vmem_{label}",

@@ -56,6 +56,9 @@ def main() -> None:
                     help="directory for the BENCH_<module>.json files")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_accuracy, bench_cache, bench_kernels,
                             bench_preprocess, bench_query, bench_serving,
                             bench_updates, bench_verd, bench_walks)

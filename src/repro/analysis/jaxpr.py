@@ -7,11 +7,12 @@ importable engine, so the kernel contract logic cannot drift across
 copies.  Functions here return :class:`~repro.analysis.registry.Finding`
 lists (for the runner) with thin ``assert_*`` wrappers (for pytest).
 
-Memory-space vocabulary (TPU Pallas on jax 0.4.x): a block mapping whose
-``transformed_block_aval.memory_space`` stringifies to ``"any"`` stays in
-HBM and is DMA'd manually by the kernel; anything else (``None`` = default
-VMEM) is staged into VMEM by the pipeline — which is exactly what the
-CSR / ``[n, L]`` index operands must never do.
+Memory-space vocabulary: a block mapping whose
+``transformed_block_aval.memory_space`` stringifies to ``"any"``
+(``pl.ANY``) stays in HBM and is DMA'd manually by the kernel; anything
+else (``"None"`` = the default VMEM, or an explicit ``"vmem"``) is staged
+into VMEM by the pipeline — which is exactly what the CSR / ``[n, L]``
+index operands must never do.
 """
 
 from __future__ import annotations
@@ -20,11 +21,11 @@ import functools
 from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-import jax.core as jcore
+import jax.extend.core as jcore
 
 from repro.analysis.registry import Finding
 
-Jaxpr = Any          # jax.core.Jaxpr (kept loose across jax versions)
+Jaxpr = Any          # jax.extend.core.Jaxpr or ClosedJaxpr
 BlockSpecs = List[Tuple[Tuple[Optional[int], ...], str]]
 
 
@@ -85,8 +86,18 @@ def pallas_block_specs(fn, *args, **kwargs) -> BlockSpecs:
         gm = eqn.params["grid_mapping"]
         for bm in gm.block_mappings:
             aval = bm.transformed_block_aval
-            blocks.append((tuple(bm.block_shape), str(aval.memory_space)))
+            blocks.append((
+                tuple(_block_dim(d) for d in bm.block_shape),
+                str(aval.memory_space),
+            ))
     return blocks
+
+
+def _block_dim(d) -> Optional[int]:
+    """One block-shape entry as an int (``None`` for a squeezed dim): the
+    installed Pallas wraps sizes as ``Blocked(block_size=...)``."""
+    d = getattr(d, "block_size", d)
+    return d if isinstance(d, int) else None
 
 
 def _block_elems(shape: Sequence[Optional[int]]) -> int:

@@ -1,14 +1,13 @@
 """The paper's own workload configs (Table 1 graphs + engine settings).
 
-The small graphs run for real (accuracy benchmarks); the billion-edge
-graphs exist as *shape* configs for the dry-run/roofline of the PPR engine
-itself (walk engine + VERD batch query on the production mesh).
+The small graphs run for real (accuracy benchmarks); the others record
+the paper's graph sizes.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,33 +39,3 @@ class PowerWalkEngineConfig:
     max_walk_steps: int = 64      # tail (1-c)^64 ~ 3e-5
     query_batch: int = 10_000     # paper's headline batch size
     top_k: int = 200
-
-
-@dataclasses.dataclass(frozen=True)
-class PPRDryRunShape:
-    """Shape cell for the distributed PPR engine dry-run."""
-    name: str
-    n: int                        # vertices
-    ell_rows: int                 # chunked-ELL rows (~m / k + n)
-    ell_k: int
-    queries: int
-    index_l: int
-    walks_per_shard: int
-
-
-def engine_dryrun_shapes() -> Tuple[PPRDryRunShape, ...]:
-    """twitter-2010-scale VERD batch query + MCFP walk cells."""
-    tw = PAPER_GRAPHS["twitter-2010"]
-    uk = PAPER_GRAPHS["uk-union"]
-    return (
-        PPRDryRunShape(
-            name="twitter_q10k",
-            n=tw.n, ell_rows=tw.m // 16 + tw.n, ell_k=16,
-            queries=10_000, index_l=667, walks_per_shard=1 << 20,
-        ),
-        PPRDryRunShape(
-            name="ukunion_q10k",
-            n=uk.n, ell_rows=uk.m // 32 + uk.n, ell_k=32,
-            queries=10_000, index_l=667, walks_per_shard=1 << 20,
-        ),
-    )

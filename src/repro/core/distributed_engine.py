@@ -35,7 +35,7 @@ query-tiled engine:
   top-L index, bucket/exchange once, then a local+gathered top-k.
 
 Everything is shard_map'd so the collective schedule is explicit and
-auditable in the dry-run HLO.
+auditable in the compiled HLO.
 """
 
 from __future__ import annotations
@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import frontier as frontier_mod
 from repro.core.graph import Graph
 from repro.core.walks import DEFAULT_C
@@ -73,8 +72,6 @@ class DistConfig:
     degree_cap: int = 0         # max out-degree; required for sparse exchange
     hub_split_degree: int = 0   # ELL row-split threshold for the sparse push
     kernel_q_tile: int = 8      # query-tile of the fused Pallas push kernel
-    kernel_interpret: Optional[bool] = None  # None = auto: interpret except
-                                # on a real TPU backend (interpret=False)
     compress_k: int = 0         # DEPRECATED: top-k'd *dense* exchange; use
                                 # exchange="sparse" + wire_k instead
     edge_chunk: int = 1 << 22   # local edge-scan chunk
@@ -126,14 +123,6 @@ class DistConfig:
         )
         return min(k, self.n_shard)
 
-    @property
-    def resolved_kernel_interpret(self) -> bool:
-        """Interpret mode for the fused push kernel: honor the explicit
-        setting, else interpret everywhere but a real TPU backend."""
-        if self.kernel_interpret is not None:
-            return bool(self.kernel_interpret)
-        return jax.default_backend() != "tpu"
-
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -141,7 +130,12 @@ class ShardedGraph:
     """Per-shard CSR slabs, stacked on a leading shard dim.
 
     row_ptr: int32[ep, n_shard + 1]   local rows (offsets into col_idx row)
-    col_idx: int32[ep, m_shard]       global destination ids (padded)
+    col_idx: int32[ep, m_shard]       global destination ids (padded); for
+                                      exchange="sparse" stored as the push
+                                      kernel's lane rows, int32[ep,
+                                      m_shard // 128 + 2, 1, 128] (the same
+                                      ids, zero-padded), so no iteration
+                                      relays the slab out
     edge_w:  f32[ep, m_shard]         1/out_deg(src), 0 on padding — only
                                       materialized for exchange="dense";
                                       the sparse step re-derives 1/deg from
@@ -156,11 +150,15 @@ class ShardedGraph:
 
     @staticmethod
     def specs(cfg: DistConfig, m_shard: int) -> "ShardedGraph":
+        from repro.kernels.frontier_push import lane_rows_shape
+
         sds = jax.ShapeDtypeStruct
-        m_w = m_shard if cfg.exchange == "dense" else 1
+        dense = cfg.exchange == "dense"
+        m_w = m_shard if dense else 1
+        col = (m_shard,) if dense else lane_rows_shape(m_shard)
         return ShardedGraph(
             row_ptr=sds((cfg.ep, cfg.n_shard + 1), jnp.int32),
-            col_idx=sds((cfg.ep, m_shard), jnp.int32),
+            col_idx=sds((cfg.ep,) + col, jnp.int32),
             edge_w=sds((cfg.ep, m_w), jnp.float32),
             dangling=sds((cfg.ep, cfg.n_shard), jnp.float32),
         )
@@ -204,10 +202,18 @@ def build_sharded_graph(graph: Graph, cfg: DistConfig) -> ShardedGraph:
         m_shard = max(m_shard, len(lc))
     m_shard = max(m_shard, 1)
     rp = np.stack([s[0] for s in slabs])
-    ci = np.stack([np.pad(s[1], (0, m_shard - len(s[1]))) for s in slabs])
     if cfg.exchange == "dense":
+        ci = np.stack([np.pad(s[1], (0, m_shard - len(s[1]))) for s in slabs])
         ew = np.stack([np.pad(s[2], (0, m_shard - len(s[2]))) for s in slabs])
-    else:  # sparse step re-derives 1/deg; skip the O(m) f32 slab entirely
+    else:
+        # the push kernel's lane rows, laid out once here
+        from repro.kernels.frontier_push import lane_rows_shape
+
+        shape = lane_rows_shape(m_shard)
+        width = shape[0] * shape[2]
+        ci = np.stack([np.pad(s[1], (0, width - len(s[1]))) for s in slabs])
+        ci = ci.reshape((ep,) + shape)
+        # the sparse step re-derives 1/deg; skip the O(m) f32 slab entirely
         ew = np.zeros((cfg.ep, 1), np.float32)
     dg = np.stack([s[3] for s in slabs])
     return ShardedGraph(
@@ -258,7 +264,9 @@ def _compress_bucket(contrib, k):
     return vals, idx.astype(jnp.int32)
 
 
-def make_verd_tile_step(cfg: DistConfig, mesh: Mesh):
+def make_verd_tile_step(
+    cfg: DistConfig, mesh: Mesh, *, kernel_interpret: bool = False
+):
     """Returns jit-able fn(graph_slabs, sources[qt], index_vals, index_idx)
     -> (topk_vals [qt, top_k], topk_idx [qt, top_k]).
 
@@ -266,10 +274,12 @@ def make_verd_tile_step(cfg: DistConfig, mesh: Mesh):
     combine + distributed top-k.  ``index_vals/idx``: [ep, n_shard, L].
     Dispatches on ``cfg.exchange``: the default ``"sparse"`` wire format
     exchanges per-owner top-``wire_k`` (value, index) pairs; ``"dense"``
-    keeps the legacy full-slab exchange as the oracle.
+    keeps the legacy full-slab exchange as the oracle.  The sparse step's
+    push kernel is compiled for the TPU; ``kernel_interpret=True`` runs it
+    through the Pallas interpreter (CPU tests).
     """
     if cfg.exchange == "sparse":
-        return _make_verd_tile_step_sparse(cfg, mesh)
+        return _make_verd_tile_step_sparse(cfg, mesh, kernel_interpret)
     return _make_verd_tile_step_dense(cfg, mesh)
 
 
@@ -371,7 +381,7 @@ def _make_verd_tile_step_dense(cfg: DistConfig, mesh: Mesh):
         P(model, None, None), P(model, None, None),
     )
     out_specs = (P(), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
@@ -383,7 +393,9 @@ def _make_verd_tile_step_dense(cfg: DistConfig, mesh: Mesh):
     return step
 
 
-def _make_verd_tile_step_sparse(cfg: DistConfig, mesh: Mesh):
+def _make_verd_tile_step_sparse(
+    cfg: DistConfig, mesh: Mesh, interpret: bool
+):
     """SparseFrontier wire format: O(Q x shards x wire_k) bytes/iteration.
 
     Per shard, per iteration: gather-push the local ``[Q, K]`` frontier
@@ -392,8 +404,7 @@ def _make_verd_tile_step_sparse(cfg: DistConfig, mesh: Mesh):
     so no gather axis exceeds ``hub_split_degree``; the kernel emits the
     per-owner top-``wire_k`` (value, local-index) buckets directly), one
     ``all_to_all``, then dedup-merge + re-compact the received partials back
-    to the ``[Q, K]`` slice.  The kernel runs ``interpret=True`` off-TPU and
-    compiled on a real TPU (``cfg.resolved_kernel_interpret``).  The
+    to the ``[Q, K]`` slice.  The
     accumulated ``s`` and the index-combine contributions stay sparse end to
     end; only the final per-shard top-k is gathered.
     """
@@ -410,7 +421,6 @@ def _make_verd_tile_step_sparse(cfg: DistConfig, mesh: Mesh):
     k_front = min(cfg.resolved_frontier_k, ns)   # local slice: <= ns distinct
     kw = cfg.resolved_wire_k
     kc = cfg.resolved_combine_wire_k
-    interpret = cfg.resolved_kernel_interpret
 
     def a2a(x):
         return jax.lax.all_to_all(
@@ -492,7 +502,7 @@ def _make_verd_tile_step_sparse(cfg: DistConfig, mesh: Mesh):
         P(model, None, None), P(model, None, None),
     )
     out_specs = (P(), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
@@ -609,7 +619,7 @@ def make_walk_counts_step(cfg: DistConfig, mesh: Mesh, *, max_steps: int = 64):
         P(),
     )
     out_specs = (P(None, model), P())
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
@@ -676,7 +686,7 @@ def make_sparse_walk_counts_step(
         P(),
     )
     out_specs = (P(), P(), P(), P(), P())
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
@@ -836,7 +846,7 @@ def make_sparse_index_build_step(
     out_specs = (
         P(model, None), P(model, None), P(model), P(model),
     ) + ((P(model, None),) if touch_bits else ())
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

@@ -180,7 +180,7 @@ def fold_topk(
     """Fold a batch of candidate columns into a running top-``k`` sketch.
 
     The streaming-accumulation primitive shared by
-    :func:`repro.core.verd.sparse_push_compact` (frontier-slot chunks) and
+    :func:`repro.core.verd.sparse_push_compact` (packed edge blocks) and
     the offline walk engine's visit-count sketches
     (:func:`repro.core.walks.simulate_walks_sparse`): concatenate the new
     candidates onto the running rows, dedup-merge, keep the top-``k``.

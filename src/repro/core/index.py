@@ -24,6 +24,7 @@ from typing import Iterable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, NamedSharding
 
 from repro.core import frontier as frontier_mod
 from repro.core import mcfp
@@ -68,22 +69,25 @@ class PPRIndex:
         primitive of incremental maintenance (``core/updates.py``).
 
         Sharded-aware: if this index lives model-sharded (the
-        ``build_index_sharded`` ``P(model, None)`` layout) the scattered
-        result is ``device_put`` back onto the same sharding, so a repaired
-        index keeps the serving path's layout instead of silently
-        gathering to one device.
+        ``build_index_sharded`` ``P(model, None)`` layout) the result keeps
+        that sharding (:func:`set_rows`), so a repaired index keeps the
+        serving path's layout instead of gathering to one device.
         """
-        rows = jnp.asarray(rows, jnp.int32)
-        new_v = self.values.at[rows].set(
-            jnp.asarray(values, self.values.dtype))
-        new_i = self.indices.at[rows].set(
-            jnp.asarray(indices, self.indices.dtype))
-        sh = getattr(self.values, "sharding", None)
-        if sh is not None and not sh.is_fully_replicated:
-            new_v = jax.device_put(new_v, sh)
-            new_i = jax.device_put(
-                new_i, getattr(self.indices, "sharding", sh))
-        return PPRIndex(values=new_v, indices=new_i, l=self.l, n=self.n)
+        return PPRIndex(
+            values=set_rows(self.values, rows, values),
+            indices=set_rows(self.indices, rows, indices),
+            l=self.l, n=self.n,
+        )
+
+
+def set_rows(dst: jax.Array, rows, src) -> jax.Array:
+    """``dst.at[rows].set(src)`` that keeps ``dst``'s mesh sharding: the
+    scattered result is put back onto it.  Every sharded producer here
+    (:func:`build_index_sharded`, checkpoint loads) lays arrays out on a
+    mesh with Auto axes, where the scatter itself needs no sharding."""
+    out = dst.at[jnp.asarray(rows, jnp.int32)].set(jnp.asarray(src, dst.dtype))
+    sh = getattr(dst, "sharding", None)
+    return jax.device_put(out, sh) if isinstance(sh, NamedSharding) else out
 
 
 def truncate_topl(estimates: jax.Array, l: int) -> Tuple[jax.Array, jax.Array]:
@@ -399,6 +403,13 @@ def _complete_stats(extra: dict, tree: dict, touch_bits: int) -> dict:
     return stats
 
 
+def sketch_width(n: int, l: int) -> int:
+    """Visit-count sketch width of the sparse builders: headroom over the
+    index width keeps the running top-L honest (entries near rank ``l``
+    compete inside the sketch before the final slice)."""
+    return min(n, max(2 * l, l + 32))
+
+
 def _build_index_sparse(
     graph: Graph,
     r: int,
@@ -433,9 +444,7 @@ def _build_index_sparse(
     """
     n = graph.n
     l = min(l, n)
-    # sketch headroom over the index width keeps the running top-L honest:
-    # entries near rank l compete inside the sketch before the final slice
-    sketch_l = min(n, max(2 * l, l + 32))
+    sketch_l = sketch_width(n, l)
     sources = np.asarray(sources, dtype=np.int32)
     n_src = len(sources)
     pad_rows = (-n_src) % source_batch
@@ -682,6 +691,9 @@ def build_index_sharded(
     """
     from repro.core.distributed_engine import DistConfig
 
+    # the index is served and repaired by plain jnp code, so its layout
+    # must not enter the array types (jax.make_mesh defaults to Explicit)
+    mesh = mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     ep = int(mesh.shape[model_axis])
     n_split = 1
     for ax in batch_axes:
@@ -692,7 +704,7 @@ def build_index_sharded(
         )
     n = graph.n
     l = min(l, n)
-    sketch_l = min(n, max(2 * l, l + 32))  # same headroom as single-device
+    sketch_l = sketch_width(n, l)  # same headroom as single-device
     ns = -(-n // ep)
     if source_batch > ns:
         # clamping changes the chunk grid — and with it the per-chunk keys.

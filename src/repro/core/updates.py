@@ -45,7 +45,7 @@ import numpy as np
 from repro.core import walks as walks_mod
 from repro.core.graph import Graph, apply_edge_updates
 from repro.core.index import (PPRIndex, build_index, build_index_sharded,
-                              sparse_chunk_estimates)
+                              set_rows, sparse_chunk_estimates)
 
 DEFAULT_C = walks_mod.DEFAULT_C
 
@@ -103,12 +103,8 @@ class TouchSketch:
     def replace_rows(self, rows, new_bits) -> "TouchSketch":
         """Functionally replace rows (sharding-preserving, like
         ``PPRIndex.replace_rows``)."""
-        b = self.bits.at[jnp.asarray(rows, jnp.int32)].set(
-            jnp.asarray(new_bits))
-        sh = getattr(self.bits, "sharding", None)
-        if sh is not None and not sh.is_fully_replicated:
-            b = jax.device_put(b, sh)
-        return TouchSketch(bits=b, hashes=self.hashes)
+        return TouchSketch(
+            bits=set_rows(self.bits, rows, new_bits), hashes=self.hashes)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -285,6 +285,7 @@ def gather_push_edges(
     c: float,
     degree_cap: int,
     hub_split_degree: int = 0,
+    window_gather=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Edge gather shared by the single-device and sharded pushes.
 
@@ -300,8 +301,9 @@ def gather_push_edges(
     ``test_properties.py``).
 
     Implemented as :func:`push_window_starts` + a window gather +
-    :func:`masked_push_from_windows` — the same three steps the DMA kernels
-    in ``repro.kernels`` run, with the ``jnp.take`` swapped for an HBM DMA.
+    :func:`masked_push_from_windows`.  The gather is a ``jnp.take`` unless
+    ``window_gather(col_idx, starts, h) -> int32[R, h]`` is given — the DMA
+    kernel ``repro.kernels.frontier_push.gather_windows`` plugs in there.
 
     Returns ``(push_v, nbrs)`` of width ``K * s * h``; ``nbrs`` are the
     ``col_idx`` destination ids, weights ``(1-c) * fv / deg``.
@@ -312,8 +314,13 @@ def gather_push_edges(
     windows = push_window_starts(
         start, degree_cap=degree_cap, hub_split_degree=hub_split_degree, m=m
     )
-    eidx = windows[..., None] + jnp.arange(h, dtype=jnp.int32)
-    gathered = jnp.take(col_idx, eidx)                        # [Q, K, s, h]
+    if window_gather is None:
+        eidx = windows[..., None] + jnp.arange(h, dtype=jnp.int32)
+        gathered = jnp.take(col_idx, eidx)                    # [Q, K, s, h]
+    else:
+        gathered = window_gather(
+            col_idx, windows.reshape(-1), h
+        ).reshape(windows.shape + (h,))
     return masked_push_from_windows(
         fv, deg, start, windows, gathered,
         c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
@@ -332,15 +339,18 @@ def gather_push_candidates(
     degree_cap: int,
     hub_split_degree: int = 0,
     seed_weights: Optional[jax.Array] = None,
+    window_gather=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Array-level gather push shared by the core op and the Pallas kernel
-    body (``kernels/frontier_push.py``); see :func:`sparse_push_candidates`
-    for semantics.  Requires ``col_idx`` non-empty."""
+    wrapper (``kernels/frontier_push.py``, which passes its DMA
+    ``window_gather``); see :func:`sparse_push_candidates` for semantics.
+    Requires ``col_idx`` non-empty."""
     start = jnp.take(row_ptr, fi)                     # [Q, K]
     deg = jnp.take(out_deg, fi)                       # [Q, K]
     push_v, nbrs = gather_push_edges(
         fv, fi, start, deg, col_idx,
         c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
+        window_gather=window_gather,
     )
     dm = jnp.sum(jnp.where(deg == 0, fv, 0.0), axis=1)  # dangling mass [Q]
     dang_v, dang_i = dangling_seed_candidates(dm, sources, seed_weights, c=c)
@@ -388,6 +398,47 @@ def sparse_push_candidates(
     )
 
 
+def gather_packed_edges(
+    fv: jax.Array,
+    start: jax.Array,
+    deg: jax.Array,
+    ends: jax.Array,
+    col_idx: jax.Array,
+    *,
+    c: float,
+    first: jax.Array,
+    lanes: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Edges ``[first, first + lanes)`` of each row's frontier, packed.
+
+    Each row's pushed edges are numbered slot after slot: slot ``j`` owns
+    ``[ends[j] - budget_j, ends[j])`` (``ends`` is the inclusive cumulative
+    sum of the per-slot edge budgets).  Lane ``e`` finds its slot by binary
+    search and reads edge ``e - (ends[slot] - budget_slot)`` of that slot's
+    CSR row, so no lane is spent on a slot's unused ``degree_cap`` padding.
+    Returns ``(push_v, nbrs)`` of width ``lanes``: weights ``(1 - c) * fv /
+    deg``, empty lanes ``(0.0, 0)`` — the same candidates, in another
+    grouping, as :func:`gather_push_edges`.
+    """
+    q, k = fv.shape
+    e = first + jnp.arange(lanes, dtype=jnp.int32)
+    slot = jax.vmap(
+        lambda row: jnp.searchsorted(row, e, side="right")
+    )(ends).astype(jnp.int32)
+    valid = e[None, :] < ends[:, -1:]
+    slot = jnp.minimum(slot, k - 1)
+    before = jnp.where(
+        slot > 0,
+        jnp.take_along_axis(ends, jnp.maximum(slot - 1, 0), axis=1), 0,
+    )
+    at = lambda x: jnp.take_along_axis(x, slot, axis=1)
+    m = col_idx.shape[0]
+    nbrs = jnp.take(col_idx, jnp.clip(at(start) + e[None, :] - before, 0, m - 1))
+    inv = 1.0 / jnp.maximum(at(deg).astype(jnp.float32), 1.0)
+    push_v = jnp.where(valid, (1.0 - c) * at(fv) * inv, 0.0)
+    return push_v, jnp.where(valid, nbrs, 0)
+
+
 def sparse_push_compact(
     graph: Graph,
     fv: jax.Array,
@@ -407,18 +458,18 @@ def sparse_push_compact(
     Semantically :func:`sparse_push_candidates` followed by
     :func:`frontier.compact`, but when the one-shot candidate tensor
     (width ``K * s * h`` ~= ``K * degree_cap``) would dwarf the compacted
-    result, the gather is streamed in frontier-slot chunks, each folded
-    into a running top-``k_out`` state — live width stays
-    ``O(max(stream target, one slot's s*h) + k_out)`` instead of
-    ``O(K * degree_cap)``.  This is what makes the relaxed hub auto-route
-    guard safe on the single-device path: one hub slot's gather is at most
-    ``degree_cap < n`` entries, and only one chunk of slots is live at a
-    time, never the K-fold product.  Exact (equal to the one-shot path, up
+    result, the frontier's real edges are streamed in fixed-width lane
+    blocks (:func:`gather_packed_edges`), each folded into a running
+    top-``k_out`` state — live width stays ``O(stream target + k_out)``
+    instead of ``O(K * degree_cap)``, and the number of blocks follows the
+    edges the frontier actually has (the largest row's), not ``K *
+    degree_cap``: on a power-law graph almost every slot's degree is far
+    below the hubs' ``degree_cap``.  Exact (equal to the one-shot path, up
     to f32 merge rounding) whenever ``k_out`` covers the merged row
     support; below that, every fold truncates by rank like any other
     top-K here, so mass is only dropped and the drift stays bounded by the
-    dropped mass.  ``stream_width`` overrides the live-width target
-    (0 = auto: ``max(4 * k_out, one slot, 4096)``).
+    dropped mass.  ``stream_width`` overrides the lane-block width
+    (0 = auto: ``max(4 * k_out, 16384)``).
     """
     q, k = fv.shape
     m = graph.m
@@ -437,47 +488,36 @@ def sparse_push_compact(
     h, s = resolve_hub_splits(cap, hub_split_degree)
     slot_w = s * h
     out_w = min(k_out, k * slot_w + s_width)  # same width as one-shot path
-    target = stream_width if stream_width > 0 else max(
-        4 * out_w, slot_w, 4096
-    )
-    if k * slot_w + s_width <= 2 * target:    # narrow enough: one-shot
+    lanes = stream_width if stream_width > 0 else max(4 * out_w, 16384)
+    if k * slot_w + s_width <= 2 * max(lanes, slot_w):  # narrow: one-shot
         cv, ci = sparse_push_candidates(
             graph, fv, fi, sources, c=c, degree_cap=degree_cap,
             hub_split_degree=hub_split_degree, seed_weights=seed_weights,
         )
         return frontier.compact(cv, ci, out_w, graph.n, threshold=threshold)
-    slots = max(1, target // slot_w)
-    # pad the slot axis to a chunk multiple: pad slots carry fv == 0, so
-    # their (masked) candidates have zero weight and compact away
-    pad = (-k) % slots
-    fv_p = jnp.pad(fv, ((0, 0), (0, pad)))
-    fi_p = jnp.pad(fi, ((0, 0), (0, pad)))
-    start = jnp.take(graph.row_ptr, fi_p)
-    deg = jnp.take(graph.out_deg, fi_p)
-    n_chunks = (k + pad) // slots
-    chunk = lambda x: x.reshape(q, n_chunks, slots).transpose(1, 0, 2)
+    start = jnp.take(graph.row_ptr, fi)
+    deg = jnp.take(graph.out_deg, fi)
+    # empty slots (fv == 0) push nothing: they get no lanes
+    budget = jnp.where(fv > 0, jnp.minimum(deg, cap), 0)
+    ends = jnp.cumsum(budget, axis=1).astype(jnp.int32)
     # dangling mass seeds the running state (the one-shot path's last
     # slot(s)); duplicate seed candidates dedup-merge on the first fold
-    dm = jnp.sum(jnp.where(deg == 0, fv_p, 0.0), axis=1)
+    dm = jnp.sum(jnp.where(deg == 0, fv, 0.0), axis=1)
     dang_v, dang_i = dangling_seed_candidates(dm, sources, seed_weights, c=c)
     run_v, run_i = frontier.topk_compact(dang_v, dang_i, out_w)
 
-    def fold(carry, xs):
-        rv, ri = carry
-        cfv, cfi, cst, cdg = xs
-        pv, nb = gather_push_edges(
-            cfv, cfi, cst, cdg, graph.col_idx, c=c, degree_cap=degree_cap,
-            hub_split_degree=hub_split_degree,
+    def fold(b, carry):
+        pv, nb = gather_packed_edges(
+            fv, start, deg, ends, graph.col_idx, c=c, first=b * lanes,
+            lanes=lanes,
         )
         # mid-stream compaction truncates by rank only; the epsilon
         # threshold applies once at the end, like the one-shot path
-        rv, ri, _ = frontier.fold_topk(rv, ri, pv, nb, out_w)
-        return (rv, ri), ()
+        rv, ri, _ = frontier.fold_topk(*carry, pv, nb, out_w)
+        return rv, ri
 
-    (run_v, run_i), _ = jax.lax.scan(
-        fold, (run_v, run_i),
-        (chunk(fv_p), chunk(fi_p), chunk(start), chunk(deg)),
-    )
+    blocks = (jnp.max(ends[:, -1]) + lanes - 1) // lanes
+    run_v, run_i = jax.lax.fori_loop(0, blocks, fold, (run_v, run_i))
     if threshold > 0.0:
         run_v = frontier.threshold_values(run_v, threshold)
         run_v, run_i = frontier.topk_compact(run_v, run_i, out_w)

@@ -384,7 +384,7 @@ def advance_cursors(
     u: jax.Array,
     *,
     use_kernel: bool = False,
-    kernel_interpret: bool = True,
+    kernel_interpret: bool = False,
 ) -> jax.Array:
     """Advance every cursor one edge (dangling vertices jump to ``sources``).
 
@@ -514,7 +514,7 @@ def simulate_walks_sparse(
     margin: float = 1.35,
     fold_width: int = 0,
     use_kernel: bool = False,
-    kernel_interpret: bool = True,
+    kernel_interpret: bool = False,
     respawn: bool = False,
     respawn_width: int = 0,
     touch_bits: int = 0,

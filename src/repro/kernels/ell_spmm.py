@@ -60,7 +60,7 @@ def ell_spmm(
     *,
     q_tile: int = 8,
     r_tile: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Raw partials ``f32[Q, rows]``; inputs must already be tile-aligned."""
     q, n = f.shape

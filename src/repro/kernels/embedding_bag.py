@@ -44,7 +44,7 @@ def embedding_bag(
     *,
     b_tile: int = 64,
     d_tile: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused bag-sum ``f32[B, D]``; inputs must be tile-aligned."""
     b, bag = ids.shape

@@ -1,33 +1,38 @@
 """Pallas TPU kernels: HBM-resident sparse-frontier gather-push.
 
-Two kernels share the DMA-gather machinery: :func:`frontier_push` is the
-single-device fused push (gather + merge + compact), and
-:func:`sharded_frontier_push` is the distributed half-iteration (local
-gather + per-owner top-k exchange buckets) used by
-``core/distributed_engine.py``'s sparse wire format.  Both support ELL hub
-splitting (``hub_split_degree``) so no gather axis exceeds the split width.
+The one irregular memory access of a sparse VERD push is the edge gather:
+every frontier slot reads a fixed-width window of ``col_idx`` starting at
+its row's CSR offset.  :func:`gather_windows` is that gather as a DMA
+kernel; :func:`frontier_push` (single-device fused push) and
+:func:`sharded_frontier_push` (the distributed half-iteration used by
+``core/distributed_engine.py``'s sparse wire format) wrap it with the same
+masking, dedup and top-k math the jnp path runs, inside one jit.  Both
+support ELL hub splitting (``hub_split_degree``).
 
 Memory layout (the PowerWalk discipline: one iteration touches only the
 frontier's out-edges, never the graph):
 
-* ``col_idx`` stays in ``pltpu.ANY`` (HBM) — it is never blocked into VMEM.
-* The CSR ``row_ptr``/``out_deg`` arrays never enter the kernel at all: the
+* ``col_idx`` stays in ``pl.ANY`` (HBM) — it is never blocked into VMEM.
+  The kernel reads it as ``[rows, 1, 128]`` lane rows (:func:`lane_rows`):
+  a TPU DMA moves whole layout tiles, so each window is read as the two
+  lane rows that cover it and rotated into place in VMEM (``pltpu.roll``).
+  The distributed engine's slabs are stored in this layout once
+  (``distributed_engine.build_sharded_graph``), so its per-iteration push
+  never copies the graph; the single-device :func:`frontier_push` takes a
+  flat ``Graph`` and builds the lane rows in its own call.
+* The CSR ``row_ptr``/``out_deg`` arrays never enter the kernel: the
   launcher turns them into per-slot ``start``/``deg`` via two O(Q*K)
-  gathers, and the per-sub-slot gather-window starts
-  (:func:`repro.core.verd.push_window_starts`) ride in as a
-  ``PrefetchScalarGridSpec`` scalar-prefetch argument, available in SMEM
-  before the kernel body runs — exactly what the per-slot DMA addresses
-  need.
-* Each grid step DMA-gathers only the width-``h`` edge windows its
-  ``q_tile`` frontier rows touch (``make_async_copy`` HBM -> VMEM scratch,
-  depth-2 double-buffered), then masks them with the same
-  :func:`repro.core.verd.masked_push_from_windows` math the jnp path uses.
+  gathers, and the per-window starts
+  (:func:`repro.core.verd.push_window_starts`) ride in as the
+  ``PrefetchScalarGridSpec`` scalar-prefetch argument, in SMEM before the
+  kernel body runs.  SMEM holds 1 MiB, so a long window list is split
+  over several ``pallas_call``s.
+* Dedup, threshold and top-k (sorts) run in jnp after the ``pallas_call``:
+  Mosaic has no sort.
 
-VMEM per step is therefore O(q_tile * K * s * h) — independent of ``n`` and
-``nnz`` (see :func:`vmem_bytes` / :func:`vmem_bytes_legacy` for the
-before/after accounting).  ``interpret=True`` (the validated mode in this
-container) runs the same DMA schedule through the Pallas interpreter; on a
-real TPU pass ``interpret=False``.
+VMEM per grid step is O(rows per step * 128) — independent of ``n`` and
+``nnz`` (see :func:`vmem_bytes`).  ``interpret=True`` runs the same DMA
+schedule through the Pallas interpreter (the CPU test mode).
 """
 
 from __future__ import annotations
@@ -42,122 +47,200 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import frontier as frontier_mod
 from repro.core import verd as verd_mod
 
+LANES = 128
+# scalar-prefetched offsets per pallas_call: 256 KiB of the 1 MiB SMEM
+SMEM_OFFSETS = 1 << 16
+# output rows per grid step: a [4096, 128] int32 block is 2 MiB (x2 buffers)
+STEP_ROWS_CAP = 4096
+# DMAs in flight per grid step
+DMA_DEPTH = 8
 
-def dma_pipeline(rows, make_dmas):
-    """Depth-2 pipelined DMA drain: the one double-buffer schedule every
-    gather kernel here shares.
 
-    ``make_dmas(r)`` returns the async copies of pipeline row ``r`` (each
-    built with its own ``sem.at[..., r % 2]`` slot, so two rows may be in
-    flight).  Row ``r + 1``'s copies are started before waiting on row
-    ``r``'s, overlapping HBM latency with the previous row's drain.
+def round_up(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
+
+
+def dma_pipeline(rows: int, make_dmas, on_row):
+    """Pipelined DMA drain: the one schedule every gather kernel here
+    shares.
+
+    ``make_dmas(r)`` returns the async copies of row ``r`` (each into buffer
+    slot ``r % DMA_DEPTH``); ``on_row(r)`` consumes that slot once the
+    copies have landed.  Up to ``DMA_DEPTH`` rows are in flight, so HBM
+    latency overlaps the rows before it.
     """
-    for dma in make_dmas(0):
-        dma.start()
+    for r in range(min(DMA_DEPTH, rows)):
+        for dma in make_dmas(r):
+            dma.start()
 
     def body(r, carry):
-        @pl.when(r + 1 < rows)
-        def _start_next():
-            for dma in make_dmas(r + 1):
-                dma.start()
-
         for dma in make_dmas(r):
             dma.wait()
+        on_row(r)
+
+        @pl.when(r + DMA_DEPTH < rows)
+        def _start_next():
+            for dma in make_dmas(r + DMA_DEPTH):
+                dma.start()
+
         return carry
 
     jax.lax.fori_loop(0, rows, body, 0)
 
 
-def _dma_gather_windows(col_hbm, win_ref, scratch, sem, *, rows, h, base):
-    """DMA gather of ``rows`` width-``h`` edge windows via
-    :func:`dma_pipeline`: ``scratch[r] <- col_idx[win[base + r] : + h]``.
-    ``win_ref`` is the scalar-prefetched flat window-start array (SMEM),
-    ``base`` the first window of this grid step."""
+def lane_rows_shape(m: int) -> tuple[int, int, int]:
+    """Shape of :func:`lane_rows` for an ``m``-edge ``col_idx``."""
+    return (m // LANES + 2, 1, LANES)
+
+
+def lane_rows_reach(col_rows: jax.Array) -> int:
+    """Edge count that windows over the lane rows ``col_rows`` may be
+    clipped to: every window starting below it has both of its covering
+    rows (the spare row included), and entries past the real edges are
+    padding that the push's degree mask drops."""
+    return (col_rows.shape[0] - 1) * LANES
+
+
+def lane_rows(col_idx: jax.Array) -> jax.Array:
+    """``col_idx`` as ``int32[rows, 1, 128]`` lane rows plus one spare row,
+    so that the two rows covering any in-range window exist.  Built as
+    whole rows + a padded two-row tail: padding before the reshape costs
+    the TPU compiler ~20 s at m = 2**24."""
+    col = col_idx.astype(jnp.int32)
+    m = col.shape[0]
+    full = m // LANES * LANES
+    tail = jnp.pad(col[full:], (0, 2 * LANES - (m - full)))
+    return jnp.concatenate([
+        col[:full].reshape(-1, 1, LANES), tail.reshape(2, 1, LANES),
+    ])
+
+
+def chunked_prefetch_call(offsets: jax.Array, step_rows: int, call):
+    """Run ``call(offsets_chunk)`` over ``offsets`` in chunks SMEM can hold
+    and concatenate the results along axis 0.  ``offsets`` must be a
+    multiple of ``step_rows`` long."""
+    per_call = max(step_rows, SMEM_OFFSETS // step_rows * step_rows)
+    total = offsets.shape[0]
+    outs = [call(offsets[i:i + per_call]) for i in range(0, total, per_call)]
+    if len(outs) == 1:
+        return outs[0]
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *outs)
+
+
+def _window_gather_kernel(win_ref, col_hbm, out_ref, buf, sem, *, rows):
+    """``out[r, :] <- col_idx[win[r] : win[r] + 128]``: DMA the two lane
+    rows that cover the window, rotate both left by the window's lane
+    offset, and splice."""
+    base = pl.program_id(0) * rows
 
     def make_dmas(r):
         return (pltpu.make_async_copy(
-            col_hbm.at[pl.ds(win_ref[base + r], h)],
-            scratch.at[r],
-            sem.at[r % 2],
+            col_hbm.at[pl.ds(win_ref[base + r] // LANES, 2)],
+            buf.at[r % DMA_DEPTH],
+            sem.at[r % DMA_DEPTH],
         ),)
 
-    dma_pipeline(rows, make_dmas)
+    def on_row(r):
+        lane = win_ref[base + r] % LANES
+        shift = (LANES - lane) % LANES
+        lo = pltpu.roll(buf[r % DMA_DEPTH, 0], shift, 1)
+        hi = pltpu.roll(buf[r % DMA_DEPTH, 1], shift, 1)
+        ids = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+        out_ref[pl.ds(r, 1), :] = jnp.where(ids < LANES - lane, lo, hi)
+
+    dma_pipeline(rows, make_dmas, on_row)
+
+
+def window_step_rows(step_windows: int, h: int) -> int:
+    """Output rows per grid step of :func:`gather_windows`: one per
+    128-wide piece of each window, capped at :data:`STEP_ROWS_CAP`."""
+    pieces = step_windows * (-(-h // LANES))
+    return round_up(min(max(pieces, 1), STEP_ROWS_CAP), 8)
+
+
+def gather_windows(
+    col_rows: jax.Array,
+    starts: jax.Array,
+    *,
+    h: int,
+    step_windows: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """``out[r, j] = col_idx[starts[r] + j]`` for ``j < h``: int32[R, h],
+    read out of ``col_rows``, the :func:`lane_rows` of ``col_idx``.
+
+    The DMA kernel behind every sparse push here.  Requires ``0 <= starts``
+    and ``starts + h <= lane_rows_reach(col_rows)`` (what
+    :func:`repro.core.verd.push_window_starts` guarantees when given that
+    ``m``).  Windows wider than 128 are read as several 128-wide pieces;
+    ``step_windows`` windows share a grid step.
+    """
+    (r_total,) = starts.shape
+    pieces = -(-h // LANES)
+    flat = (
+        starts.astype(jnp.int32)[:, None]
+        + LANES * jnp.arange(pieces, dtype=jnp.int32)
+    ).reshape(-1)
+    rows = window_step_rows(step_windows, h)
+    flat = jnp.pad(flat, (0, round_up(flat.shape[0], rows) - flat.shape[0]))
+
+    def call(offsets):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                 # the window starts
+            grid=(offsets.shape[0] // rows,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # col_idx: HBM
+            out_specs=pl.BlockSpec((rows, LANES), lambda i, w: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((DMA_DEPTH, 2, 1, LANES), jnp.int32),
+                pltpu.SemaphoreType.DMA((DMA_DEPTH,)),
+            ],
+        )
+        return pl.pallas_call(
+            functools.partial(_window_gather_kernel, rows=rows),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (offsets.shape[0], LANES), jnp.int32),
+            interpret=interpret,
+        )(offsets, col_rows)
+
+    out = chunked_prefetch_call(flat, rows, call)
+    return out[: r_total * pieces].reshape(r_total, pieces * LANES)[:, :h]
 
 
 def vmem_bytes(
-    q_tile: int, k: int, k_out: int, *,
-    degree_cap: int, hub_split_degree: int = 0,
+    q_tile: int, k: int, *, degree_cap: int, hub_split_degree: int = 0,
 ) -> int:
-    """Per-grid-step VMEM of the HBM-resident push: frontier blocks +
-    gather scratch + outputs.  Independent of ``n`` and ``nnz``."""
+    """Per-grid-step VMEM of the push's window gather: the double-buffered
+    output block plus the in-flight lane rows.  Independent of ``n`` and
+    ``nnz``."""
     h, s = verd_mod.resolve_hub_splits(degree_cap, hub_split_degree)
-    blocks = q_tile * k * 12 + q_tile * 4      # fv f32 + start/deg i32 + src
-    scratch = q_tile * k * s * h * 4           # gathered edge windows
-    return blocks + scratch + q_tile * k_out * 8
+    rows = window_step_rows(q_tile * k * s, h)
+    # a [2, 1, 128] int32 buffer pads to two (8, 128) tiles in VMEM
+    return 2 * rows * LANES * 4 + DMA_DEPTH * 2 * 8 * LANES * 4
 
 
 def vmem_bytes_legacy(
-    q_tile: int, k: int, k_out: int, *,
+    q_tile: int, k: int, *,
     n: int, m: int, degree_cap: int, hub_split_degree: int = 0,
 ) -> int:
-    """What the pre-HBM-resident kernel held per step: the same tiles plus
-    the whole CSR (``row_ptr``/``out_deg``/``col_idx``) as resident
-    whole-array blocks — O(nnz) VMEM that made ``interpret=False``
-    impossible at scale."""
+    """What a kernel holding the whole CSR (``row_ptr``/``out_deg``/
+    ``col_idx``) as resident whole-array blocks would need per step — the
+    O(nnz) VMEM that makes compiling at scale impossible."""
     csr = (n + 1) * 4 + n * 4 + m * 4
     return vmem_bytes(
-        q_tile, k, k_out,
-        degree_cap=degree_cap, hub_split_degree=hub_split_degree,
+        q_tile, k, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
     ) + csr
 
 
-def _dma_gathered_push(
-    win_ref, fv_ref, start_ref, deg_ref, col_hbm, scratch, sem, *,
-    c: float, degree_cap: int, hub_split_degree: int, m: int,
-):
-    """The gather half both kernel bodies share: DMA this grid step's edge
-    windows out of HBM and mask them into ``(push_v, nbrs)`` candidates.
-    Also returns the tile's ``(fv, deg)`` for the callers' epilogues
-    (dangling mass / bucketing)."""
-    i = pl.program_id(0)
-    q_tile, k = fv_ref.shape
-    h, s = verd_mod.resolve_hub_splits(degree_cap, hub_split_degree)
-    rows = q_tile * k * s
-    _dma_gather_windows(
-        col_hbm, win_ref, scratch, sem, rows=rows, h=h, base=i * rows
-    )
-    fv, start, deg = fv_ref[...], start_ref[...], deg_ref[...]
-    # recompute the (clipped) window starts for the masking math — the same
-    # pure function that produced the prefetched DMA addresses
-    windows = verd_mod.push_window_starts(
-        start, degree_cap=degree_cap, hub_split_degree=hub_split_degree, m=m
-    )
-    gathered = scratch[...].reshape(q_tile, k, s, h)
-    push_v, nbrs = verd_mod.masked_push_from_windows(
-        fv, deg, start, windows, gathered,
-        c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
-    )
-    return fv, deg, push_v, nbrs
-
-
-def _frontier_push_kernel(
-    win_ref, fv_ref, start_ref, deg_ref, src_ref, col_hbm,
-    ov_ref, oi_ref, nbr_scratch, sem, *,
-    c: float, degree_cap: int, threshold: float, hub_split_degree: int,
-    m: int,
-):
-    fv, deg, push_v, nbrs = _dma_gathered_push(
-        win_ref, fv_ref, start_ref, deg_ref, col_hbm, nbr_scratch, sem,
-        c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree, m=m,
-    )
-    dm = jnp.sum(jnp.where(deg == 0, fv, 0.0), axis=1)  # dangling mass
-    cand_v = jnp.concatenate([push_v, (1.0 - c) * dm[:, None]], axis=1)
-    cand_i = jnp.concatenate([nbrs, src_ref[...]], axis=1)
-    ov, oi = frontier_mod.compact_arrays(
-        cand_v, cand_i, ov_ref.shape[1], threshold=threshold
-    )
-    ov_ref[...] = ov
-    oi_ref[...] = oi
+def _window_gather(q_tile: int, k: int, s: int, interpret: bool):
+    """The :func:`gather_windows` callable the jnp push math takes in place
+    of its ``jnp.take`` (``verd.gather_push_edges(window_gather=...)``)."""
+    def gather(col_idx, starts, h):
+        return gather_windows(
+            lane_rows(col_idx), starts, h=h, step_windows=q_tile * k * s,
+            interpret=interpret,
+        )
+    return gather
 
 
 @functools.partial(
@@ -179,82 +262,26 @@ def frontier_push(
     threshold: float = 0.0,
     q_tile: int = 8,
     hub_split_degree: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused sparse push; Q must be a multiple of ``q_tile`` (see
-    ``ops.frontier_push`` for the padding wrapper).  ``hub_split_degree``
-    bounds the per-sub-slot gather width (ELL hub splitting) without
-    changing the result.  Requires ``col_idx`` non-empty (the edgeless case
-    is the wrapper's jnp fallback)."""
+    """Fused sparse push: kernel edge gather, then mask, dangling mass and
+    dedup + threshold + top-``k_out`` in jnp.  ``q_tile`` queries' windows
+    share a grid step.  ``hub_split_degree`` bounds the per-sub-slot
+    gather width (ELL hub splitting) without changing the result.
+    Requires ``col_idx`` non-empty (the edgeless case is the wrapper's jnp
+    fallback)."""
     q, k = fv.shape
     assert fi.shape == (q, k) and sources.shape[0] == q
-    assert q % q_tile == 0, (q, q_tile)
-    m = col_idx.shape[0]
-    degree_cap = min(degree_cap, max(m, 1))  # no row has more than m edges
-    h, s = verd_mod.resolve_hub_splits(degree_cap, hub_split_degree)
-    fi32 = fi.astype(jnp.int32)
-    # per-slot CSR offsets: two O(Q*K) gathers — row_ptr/out_deg themselves
-    # never enter the kernel
-    start = jnp.take(row_ptr, fi32).astype(jnp.int32)
-    deg = jnp.take(out_deg, fi32).astype(jnp.int32)
-    windows = verd_mod.push_window_starts(
-        start, degree_cap=degree_cap, hub_split_degree=hub_split_degree, m=m
-    ).reshape(-1)
-    src2d = sources.reshape(q, 1).astype(jnp.int32)
-    kernel = functools.partial(
-        _frontier_push_kernel, c=c, degree_cap=degree_cap,
-        threshold=threshold, hub_split_degree=hub_split_degree, m=m,
+    _, s = verd_mod.resolve_hub_splits(
+        min(degree_cap, max(col_idx.shape[0], 1)), hub_split_degree)
+    cand_v, cand_i = verd_mod.gather_push_candidates(
+        fv, fi.astype(jnp.int32), sources, row_ptr, out_deg, col_idx,
+        c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
+        window_gather=_window_gather(q_tile, k, s, interpret),
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                 # the flat window starts
-        grid=(q // q_tile,),
-        in_specs=[
-            pl.BlockSpec((q_tile, k), lambda i, w: (i, 0)),
-            pl.BlockSpec((q_tile, k), lambda i, w: (i, 0)),
-            pl.BlockSpec((q_tile, k), lambda i, w: (i, 0)),
-            pl.BlockSpec((q_tile, 1), lambda i, w: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # col_idx: HBM resident
-        ],
-        out_specs=[
-            pl.BlockSpec((q_tile, k_out), lambda i, w: (i, 0)),
-            pl.BlockSpec((q_tile, k_out), lambda i, w: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((q_tile * k * s, h), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+    return frontier_mod.compact_arrays(
+        cand_v, cand_i, k_out, threshold=threshold
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q, k_out), jnp.float32),
-            jax.ShapeDtypeStruct((q, k_out), jnp.int32),
-        ],
-        interpret=interpret,
-    )(windows, fv, start, deg, src2d, col_idx)
-
-
-# ---------------------------------------------------------------------------
-# sharded push: local gather + per-owner top-k buckets (the pre-exchange
-# compute of the distributed sparse wire format)
-# ---------------------------------------------------------------------------
-
-def _sharded_push_kernel(
-    win_ref, fv_ref, start_ref, deg_ref, col_hbm, ov_ref, oi_ref,
-    nbr_scratch, sem, *,
-    c: float, degree_cap: int, hub_split_degree: int, ep: int,
-    n_shard: int, m: int,
-):
-    _, _, push_v, nbrs = _dma_gathered_push(
-        win_ref, fv_ref, start_ref, deg_ref, col_hbm, nbr_scratch, sem,
-        c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree, m=m,
-    )
-    bv, bi = frontier_mod.bucket_by_owner(
-        push_v, nbrs, ep, n_shard, ov_ref.shape[2]
-    )
-    ov_ref[...] = bv
-    oi_ref[...] = bi
 
 
 @functools.partial(
@@ -266,7 +293,7 @@ def sharded_frontier_push(
     fv: jax.Array,
     fi: jax.Array,
     row_ptr: jax.Array,
-    col_idx: jax.Array,
+    col_rows: jax.Array,
     *,
     c: float,
     degree_cap: int,
@@ -275,66 +302,44 @@ def sharded_frontier_push(
     wire_k: int,
     hub_split_degree: int = 0,
     q_tile: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """One shard's half-iteration of the distributed sparse exchange.
 
     ``fv/fi f32|int32[Q, K]``: the shard's local frontier slice (indices are
-    local row ids).  ``row_ptr int32[n_shard + 1]`` / ``col_idx int32[m]``:
-    the shard's CSR slab, destination ids global.  ``row_ptr`` is consumed
-    outside the kernel (per-slot ``start``/``deg`` gathers + the
-    scalar-prefetched window starts); ``col_idx`` stays HBM resident and is
-    DMA-gathered per grid step.  Emits the per-owner top-``wire_k`` exchange
+    local row ids).  ``row_ptr int32[n_shard + 1]`` / ``col_rows``: the
+    shard's CSR slab, destination ids global, its ``col_idx`` stored as
+    :func:`lane_rows` (``int32[rows, 1, 128]``, the layout
+    ``build_sharded_graph`` keeps), so no call copies the slab.  The kernel
+    gathers the edge windows out of the HBM-resident lane rows; the
+    per-owner top-``wire_k`` bucketing runs in jnp after it.  Emits the exchange
     buckets ``(vals f32[Q, ep, wire_k], idx int32[Q, ep, wire_k])`` with
     owner-local indices — exactly what ``all_to_all`` puts on the wire.
     Dangling mass is the caller's business (it needs a cross-shard psum).
-    Same grid/tiling contract as :func:`frontier_push`; Q must be a multiple
-    of ``q_tile``.
     """
     q, k = fv.shape
-    assert fi.shape == (q, k)
-    assert q % q_tile == 0, (q, q_tile)
-    m = col_idx.shape[0]
-    degree_cap = min(degree_cap, max(m, 1))
+    assert fi.shape == (q, k) and col_rows.shape[1:] == (1, LANES)
+    m = lane_rows_reach(col_rows)
+    degree_cap = min(degree_cap, m)
     h, s = verd_mod.resolve_hub_splits(degree_cap, hub_split_degree)
     fi32 = fi.astype(jnp.int32)
     local_deg = row_ptr[1:] - row_ptr[:-1]
     start = jnp.take(row_ptr, fi32).astype(jnp.int32)
     deg = jnp.take(local_deg, fi32).astype(jnp.int32)
+    # verd.gather_push_edges' three steps, the gather reading the stored
+    # lane rows
     windows = verd_mod.push_window_starts(
-        start, degree_cap=degree_cap, hub_split_degree=hub_split_degree, m=m
-    ).reshape(-1)
-    kernel = functools.partial(
-        _sharded_push_kernel, c=c, degree_cap=degree_cap,
-        hub_split_degree=hub_split_degree, ep=ep, n_shard=n_shard, m=m,
+        start, degree_cap=degree_cap, hub_split_degree=hub_split_degree, m=m,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(q // q_tile,),
-        in_specs=[
-            pl.BlockSpec((q_tile, k), lambda i, w: (i, 0)),
-            pl.BlockSpec((q_tile, k), lambda i, w: (i, 0)),
-            pl.BlockSpec((q_tile, k), lambda i, w: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # col_idx: HBM resident
-        ],
-        out_specs=[
-            pl.BlockSpec((q_tile, ep, wire_k), lambda i, w: (i, 0, 0)),
-            pl.BlockSpec((q_tile, ep, wire_k), lambda i, w: (i, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((q_tile * k * s, h), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q, ep, wire_k), jnp.float32),
-            jax.ShapeDtypeStruct((q, ep, wire_k), jnp.int32),
-        ],
+    gathered = gather_windows(
+        col_rows, windows.reshape(-1), h=h, step_windows=q_tile * k * s,
         interpret=interpret,
-    )(windows, fv, start, deg, col_idx)
+    ).reshape(windows.shape + (h,))
+    push_v, nbrs = verd_mod.masked_push_from_windows(
+        fv, deg, start, windows, gathered,
+        c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
+    )
+    return frontier_mod.bucket_by_owner(push_v, nbrs, ep, n_shard, wire_k)
 
 
 # ---------------------------------------------------------------------------
@@ -366,8 +371,8 @@ def _contract_spec_frontier_push():
             q_tile=q_tile, interpret=True,
         ),
         args=(fv, fi, srcs, g.row_ptr, g.out_deg, g.col_idx),
-        hbm_shapes=[(g.m,)],
-        vmem_budget=q_tile * k * s * h + q_tile * max(k, k_out),
+        hbm_shapes=[lane_rows_shape(g.m)],
+        vmem_budget=window_step_rows(q_tile * k * s, h) * LANES,
     )
 
 
@@ -386,7 +391,6 @@ def _contract_spec_sharded_push():
     ns = cfg.n_shard
     fv = jnp.asarray(rng.random((q, k)), jnp.float32)
     fi = jnp.clip(jnp.asarray(rng.integers(0, n, (q, k)), jnp.int32), 0, ns - 1)
-    m_shard = slabs.col_idx.shape[1]
     h, s = verd_mod.resolve_hub_splits(cap, 0)
     return dict(
         fn=functools.partial(
@@ -394,8 +398,8 @@ def _contract_spec_sharded_push():
             wire_k=wire_k, q_tile=q_tile, interpret=True,
         ),
         args=(fv, fi, slabs.row_ptr[0], slabs.col_idx[0]),
-        hbm_shapes=[(m_shard,)],
-        vmem_budget=q_tile * k * s * h + q_tile * 2 * wire_k,
+        hbm_shapes=[slabs.col_idx.shape[1:]],
+        vmem_budget=window_step_rows(q_tile * k * s, h) * LANES,
     )
 
 

@@ -24,7 +24,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import frontier as frontier_mod
 from repro.core import verd as verd_mod
-from repro.kernels.frontier_push import dma_pipeline
+from repro.kernels.frontier_push import (DMA_DEPTH, LANES,
+                                         chunked_prefetch_call, dma_pipeline,
+                                         round_up)
 
 
 def _index_combine_kernel(s_ref, f_ref, vals_ref, idx_ref, o_ref):
@@ -57,7 +59,7 @@ def index_combine(
     *,
     q_tile: int = 8,
     v_tile: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused combine; inputs must be tile-aligned (see ops.index_combine).
 
@@ -91,43 +93,108 @@ def index_combine(
 # whole-array index blocks anywhere.
 # ---------------------------------------------------------------------------
 
-def _index_combine_sparse_kernel(
-    fi_ref, sv_ref, si_ref, fv_ref, vals_hbm, idx_hbm, ov_ref, oi_ref,
-    vals_scratch, idx_scratch, sem,
-):
-    i = pl.program_id(0)
-    q_tile, k = fv_ref.shape
-    rows = q_tile * k
+# a TPU DMA moves whole (8, 128) tiles of a 2-D array
+SUBLANES = 8
+# output block budget per grid step (both arrays, one buffer)
+STEP_BYTES_CAP = 2 << 20
 
-    # DMA the K touched index rows of this tile out of HBM; fi_ref is the
-    # scalar-prefetched flat row-id array (SMEM)
+
+def padded_index_shape(n: int, l: int) -> tuple[int, int]:
+    """The tile-aligned ``[n, L]`` shape the row-gather kernel reads."""
+    return round_up(n, SUBLANES), round_up(l, LANES)
+
+
+def row_step_rows(step_rows: int, l: int) -> int:
+    """Gathered rows per grid step: ``step_rows`` capped so both output
+    blocks stay within :data:`STEP_BYTES_CAP`."""
+    cap = max(SUBLANES, STEP_BYTES_CAP // (round_up(l, LANES) * 8))
+    return round_up(min(max(step_rows, 1), cap), SUBLANES)
+
+
+def _row_gather_kernel(
+    row_ref, vals_hbm, idx_hbm, ov_ref, oi_ref, vbuf, ibuf, sem, *, rows,
+):
+    """``out[r] <- index[row[r]]``: DMA the 8-row tile holding the row out
+    of each HBM array, then copy the row into the output block."""
+    base = pl.program_id(0) * rows
+
     def make_dmas(r):
-        row = fi_ref[i * rows + r]
+        tile = pl.multiple_of(row_ref[base + r] // SUBLANES * SUBLANES,
+                              SUBLANES)
+        slot = r % DMA_DEPTH
         return (
             pltpu.make_async_copy(
-                vals_hbm.at[pl.ds(row, 1), :],
-                vals_scratch.at[pl.ds(r, 1), :],
-                sem.at[0, r % 2],
-            ),
+                vals_hbm.at[pl.ds(tile, SUBLANES)], vbuf.at[slot],
+                sem.at[0, slot]),
             pltpu.make_async_copy(
-                idx_hbm.at[pl.ds(row, 1), :],
-                idx_scratch.at[pl.ds(r, 1), :],
-                sem.at[1, r % 2],
-            ),
+                idx_hbm.at[pl.ds(tile, SUBLANES)], ibuf.at[slot],
+                sem.at[1, slot]),
         )
 
-    dma_pipeline(rows, make_dmas)
+    def on_row(r):
+        sub = pl.ds(row_ref[base + r] % SUBLANES, 1)
+        ov_ref[pl.ds(r, 1), :] = vbuf[r % DMA_DEPTH, sub, :]
+        oi_ref[pl.ds(r, 1), :] = ibuf[r % DMA_DEPTH, sub, :]
 
-    l = vals_scratch.shape[1]
-    iv = vals_scratch[...].reshape(q_tile, k, l)
-    ii = idx_scratch[...].reshape(q_tile, k, l)
-    # same array-level math as the jnp core op — single source of truth
-    cand_v, cand_i = verd_mod.combine_candidates_from_rows(
-        sv_ref[...], si_ref[...], fv_ref[...], iv, ii
-    )
-    ov, oi = frontier_mod.compact_arrays(cand_v, cand_i, ov_ref.shape[1])
-    ov_ref[...] = ov
-    oi_ref[...] = oi
+    dma_pipeline(rows, make_dmas, on_row)
+
+
+def gather_index_rows(
+    vals: jax.Array,
+    idx: jax.Array,
+    rows: jax.Array,
+    *,
+    step_rows: int,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """``(vals[rows], idx[rows])`` via the DMA kernel: f32|int32[R, L].
+
+    The index is padded to :func:`padded_index_shape` first: Mosaic DMAs
+    only whole tiles, and XLA stores an ``[n, 667]`` array in a layout the
+    kernel cannot take, so this copy happens in either case.  It is a copy
+    of the whole index per call (a no-op only for an index whose shape is
+    already aligned), which is why no served or build path calls this
+    kernel yet.
+    """
+    n, l = vals.shape
+    (r_total,) = rows.shape
+    n_p, l_p = padded_index_shape(n, l)
+    vals_p = jnp.pad(vals, ((0, n_p - n), (0, l_p - l)))
+    idx_p = jnp.pad(idx.astype(jnp.int32), ((0, n_p - n), (0, l_p - l)))
+    step = row_step_rows(step_rows, l)
+    flat = jnp.clip(rows.astype(jnp.int32), 0, n - 1)
+    flat = jnp.pad(flat, (0, round_up(r_total, step) - r_total))
+
+    def call(offsets):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                 # the touched row ids
+            grid=(offsets.shape[0] // step,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),   # index values: HBM
+                pl.BlockSpec(memory_space=pl.ANY),   # index columns: HBM
+            ],
+            out_specs=[
+                pl.BlockSpec((step, l_p), lambda i, r: (i, 0)),
+                pl.BlockSpec((step, l_p), lambda i, r: (i, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((DMA_DEPTH, SUBLANES, l_p), vals.dtype),
+                pltpu.VMEM((DMA_DEPTH, SUBLANES, l_p), jnp.int32),
+                pltpu.SemaphoreType.DMA((2, DMA_DEPTH)),
+            ],
+        )
+        return pl.pallas_call(
+            functools.partial(_row_gather_kernel, rows=step),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((offsets.shape[0], l_p), vals.dtype),
+                jax.ShapeDtypeStruct((offsets.shape[0], l_p), jnp.int32),
+            ],
+            interpret=interpret,
+        )(offsets, vals_p, idx_p)
+
+    ov, oi = chunked_prefetch_call(flat, step, call)
+    return ov[:r_total, :l], oi[:r_total, :l]
 
 
 @functools.partial(
@@ -143,64 +210,38 @@ def index_combine_sparse(
     *,
     k_out: int,
     q_tile: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused sparse combine + top-k; Q must be a multiple of ``q_tile``
-    (``ops.index_combine_sparse`` pads).  The ``[n, L]`` index arrays stay
-    in ``pltpu.ANY`` (HBM); the ``K`` touched rows per tile are
-    scalar-prefetch addressed and DMA-gathered into VMEM scratch, so VMEM
-    per step is O(q_tile * K * L) — independent of ``n``."""
+    """Fused sparse combine + top-k: the kernel gathers the ``K`` touched
+    index rows of each query out of the HBM-resident ``[n, L]`` arrays
+    (``q_tile`` queries' rows per grid step, capped by VMEM), then the
+    combine math and dedup + top-``k_out`` run in jnp."""
     q, k = fv.shape
-    s_w = sv.shape[1]
     n, l = vals.shape
-    assert si.shape == (q, s_w) and fi.shape == (q, k)
+    assert si.shape == sv.shape and fi.shape == (q, k)
     assert idx.shape == (n, l)
-    assert q % q_tile == 0, (q, q_tile)
-    fi_flat = jnp.clip(fi.astype(jnp.int32), 0, n - 1).reshape(-1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                 # the flat touched-row ids
-        grid=(q // q_tile,),
-        in_specs=[
-            pl.BlockSpec((q_tile, s_w), lambda i, r: (i, 0)),
-            pl.BlockSpec((q_tile, s_w), lambda i, r: (i, 0)),
-            pl.BlockSpec((q_tile, k), lambda i, r: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # index values: HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # index columns: HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((q_tile, k_out), lambda i, r: (i, 0)),
-            pl.BlockSpec((q_tile, k_out), lambda i, r: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((q_tile * k, l), vals.dtype),
-            pltpu.VMEM((q_tile * k, l), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
+    iv, ii = gather_index_rows(
+        vals, idx, fi.reshape(-1), step_rows=q_tile * k, interpret=interpret,
     )
-    return pl.pallas_call(
-        _index_combine_sparse_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q, k_out), jnp.float32),
-            jax.ShapeDtypeStruct((q, k_out), jnp.int32),
-        ],
-        interpret=interpret,
-    )(fi_flat, sv, si, fv, vals, idx)
+    # same array-level math as the jnp core op — single source of truth
+    cand_v, cand_i = verd_mod.combine_candidates_from_rows(
+        sv, si, fv, iv.reshape(q, k, l), ii.reshape(q, k, l)
+    )
+    return frontier_mod.compact_arrays(cand_v, cand_i, k_out)
 
 
-def sparse_vmem_bytes(q_tile: int, k: int, s_w: int, l: int, k_out: int) -> int:
-    """Per-grid-step VMEM of the HBM-resident sparse combine."""
-    blocks = q_tile * (2 * s_w * 4 + k * 4)    # sv/si + fv tiles
-    scratch = q_tile * k * l * 8               # gathered vals + idx rows
-    return blocks + scratch + q_tile * k_out * 8
+def sparse_vmem_bytes(q_tile: int, k: int, l: int) -> int:
+    """Per-grid-step VMEM of the HBM-resident sparse combine: the
+    double-buffered output blocks plus the in-flight index tiles."""
+    l_p = round_up(l, LANES)
+    rows = row_step_rows(q_tile * k, l)
+    return 2 * rows * l_p * 8 + DMA_DEPTH * SUBLANES * l_p * 8
 
 
-def sparse_vmem_bytes_legacy(
-    q_tile: int, k: int, s_w: int, l: int, k_out: int, *, n: int
-) -> int:
-    """Pre-HBM-resident accounting: the same tiles plus both whole ``[n,
-    L]`` index arrays resident per step."""
-    return sparse_vmem_bytes(q_tile, k, s_w, l, k_out) + 2 * n * l * 4
+def sparse_vmem_bytes_legacy(q_tile: int, k: int, l: int, *, n: int) -> int:
+    """What a kernel holding both whole ``[n, L]`` index arrays as resident
+    blocks would need per step."""
+    return sparse_vmem_bytes(q_tile, k, l) + 2 * n * l * 4
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +271,8 @@ def _contract_spec_index_combine():
             index_combine_sparse, k_out=k_out, q_tile=q_tile, interpret=True,
         ),
         args=(sv, si, fv, fi, vals, idx),
-        hbm_shapes=[(n, l)],
-        vmem_budget=q_tile * k * l + q_tile * max(s_w, k, k_out) * 2,
+        hbm_shapes=[padded_index_shape(n, l)],
+        vmem_budget=row_step_rows(q_tile * k, l) * round_up(l, LANES),
     )
 
 

@@ -14,8 +14,8 @@ expose drop-in replacements for the pure-jnp core ops:
   the offline walk engine's fused bulk advance
 * :func:`embedding_bag` <-> :func:`repro.models.recsys.embedding` bag path
 
-``interpret=True`` (default here) runs the kernel bodies in Python on CPU —
-the validation mode for this container; on TPU pass ``interpret=False``.
+Kernels compile for the TPU by default; ``interpret=True`` runs the kernel
+bodies through the Pallas interpreter instead (the CPU test mode).
 """
 
 from __future__ import annotations
@@ -74,7 +74,7 @@ def ell_push(
     *,
     q_tile: int = 8,
     r_tile: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """``frontier @ A0`` via the Pallas kernel; f32[Q, n] -> f32[Q, n].
 
@@ -104,7 +104,7 @@ def index_combine(
     *,
     q_tile: int = 8,
     v_tile: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused ``s + f @ P_hat``; pads Q and the vertex axis to tiles."""
     q, n = s.shape
@@ -130,9 +130,9 @@ def frontier_push(
     threshold: float = 0.0,
     q_tile: int = 8,
     hub_split_degree: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> SparseFrontier:
-    """One fused sparse VERD push via the Pallas kernel; pads Q to the tile.
+    """One fused sparse VERD push via the Pallas kernel.
 
     Drop-in for ``verd.sparse_push_candidates`` + ``frontier.compact``:
     returns the new frontier, compacted to ``k_out``.
@@ -145,26 +145,21 @@ def frontier_push(
             cv, ci, k_out, graph.n, threshold=threshold
         )
     _invocations["frontier_push"] += 1  # counted only when the kernel runs
-    q = f.values.shape[0]
-    fv = _pad_to(f.values, 0, q_tile)
-    fi = _pad_to(f.indices, 0, q_tile)
-    src = _pad_to(sources.astype(jnp.int32), 0, q_tile)
     ov, oi = _push.frontier_push(
-        fv, fi, src, graph.row_ptr, graph.out_deg, graph.col_idx,
+        f.values, f.indices, sources.astype(jnp.int32),
+        graph.row_ptr, graph.out_deg, graph.col_idx,
         c=c, degree_cap=degree_cap, k_out=k_out, threshold=threshold,
         q_tile=q_tile, hub_split_degree=hub_split_degree,
         interpret=interpret,
     )
-    return SparseFrontier(
-        values=ov[:q], indices=oi[:q], k=k_out, n=graph.n
-    )
+    return SparseFrontier(values=ov, indices=oi, k=ov.shape[1], n=graph.n)
 
 
 def sharded_frontier_push(
     fv: jax.Array,
     fi: jax.Array,
     row_ptr: jax.Array,
-    col_idx: jax.Array,
+    col_rows: jax.Array,
     *,
     c: float,
     degree_cap: int,
@@ -173,26 +168,23 @@ def sharded_frontier_push(
     wire_k: int,
     hub_split_degree: int = 0,
     q_tile: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """One shard's local push + per-owner exchange buckets; pads Q.
+    """One shard's local push + per-owner exchange buckets.
 
     Drop-in for the pre-``all_to_all`` compute of the distributed sparse
     wire format (``verd.gather_push_edges`` + ``frontier.bucket_by_owner``);
     returns ``(vals f32[Q, ep, wire_k], idx int32[Q, ep, wire_k])`` with
-    owner-local indices.
+    owner-local indices.  ``col_rows`` is the shard's ``col_idx`` as lane
+    rows (``ShardedGraph.col_idx[shard]``).
     """
     _invocations["sharded_frontier_push"] += 1
-    q = fv.shape[0]
-    fv_p = _pad_to(fv, 0, q_tile)
-    fi_p = _pad_to(fi, 0, q_tile)
-    ov, oi = _push.sharded_frontier_push(
-        fv_p, fi_p, row_ptr, col_idx,
+    return _push.sharded_frontier_push(
+        fv, fi, row_ptr, col_rows,
         c=c, degree_cap=degree_cap, ep=ep, n_shard=n_shard, wire_k=wire_k,
         hub_split_degree=hub_split_degree, q_tile=q_tile,
         interpret=interpret,
     )
-    return ov[:q], oi[:q]
 
 
 def index_combine_sparse(
@@ -203,24 +195,19 @@ def index_combine_sparse(
     *,
     k_out: int,
     q_tile: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> SparseFrontier:
-    """Fused sparse ``s + f @ P_hat`` + top-k via the Pallas kernel; pads Q.
+    """Fused sparse ``s + f @ P_hat`` + top-k via the Pallas kernel.
 
     Drop-in for ``verd.combine_with_index_sparse`` at ``out_k=k_out``.
     """
     _invocations["index_combine_sparse"] += 1
-    q = f.values.shape[0]
-    sv = _pad_to(s.values, 0, q_tile)
-    si = _pad_to(s.indices, 0, q_tile)
-    fv = _pad_to(f.values, 0, q_tile)
-    fi = _pad_to(f.indices, 0, q_tile)
     ov, oi = _comb.index_combine_sparse(
-        sv, si, fv, fi, vals, idx, k_out=k_out, q_tile=q_tile,
-        interpret=interpret,
+        s.values, s.indices, f.values, f.indices, vals, idx, k_out=k_out,
+        q_tile=q_tile, interpret=interpret,
     )
-    n = vals.shape[0]
-    return SparseFrontier(values=ov[:q], indices=oi[:q], k=k_out, n=n)
+    return SparseFrontier(
+        values=ov, indices=oi, k=ov.shape[1], n=vals.shape[0])
 
 
 def walk_step(
@@ -231,33 +218,25 @@ def walk_step(
     out_deg: jax.Array,
     col_idx: jax.Array,
     *,
-    w_tile: int = 128,
-    interpret: bool = True,
+    w_tile: int = _walk.TILE_WALKS,
+    interpret: bool = False,
 ) -> jax.Array:
-    """One fused bulk walk advance via the Pallas kernel; pads W to the tile.
+    """One fused bulk walk advance via the Pallas kernel.
 
     Drop-in for the jnp path of :func:`repro.core.walks.advance_cursors`
     (bit-identical under the same uniforms): accepts any cursor shape,
-    flattens, pads the walk axis with harmless dangling-style rows (pad
-    cursors/sources are vertex 0 — their sampled address is clipped in
-    range and the result rows are sliced off), and restores the shape.
+    flattens, advances, and restores the shape.
     """
     if col_idx.shape[0] == 0:  # edgeless graph: every walk jumps home
         return jnp.broadcast_to(sources, cursors.shape).astype(jnp.int32)
     _invocations["walk_step"] += 1
     shape = cursors.shape
-    cur = cursors.reshape(-1)
-    src = jnp.broadcast_to(sources, shape).reshape(-1)
-    uu = u.reshape(-1)
-    w = cur.shape[0]
-    cur_p = _pad_to(cur, 0, w_tile)
-    src_p = _pad_to(src, 0, w_tile)
-    u_p = _pad_to(uu, 0, w_tile)
     out = _walk.walk_step(
-        cur_p, src_p, u_p, row_ptr, out_deg, col_idx,
+        cursors.reshape(-1), jnp.broadcast_to(sources, shape).reshape(-1),
+        u.reshape(-1), row_ptr, out_deg, col_idx,
         w_tile=w_tile, interpret=interpret,
     )
-    return out[:w].reshape(shape)
+    return out.reshape(shape)
 
 
 @functools.partial(
@@ -270,7 +249,7 @@ def embedding_bag(
     *,
     b_tile: int = 64,
     d_tile: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Bag-sum lookup; pads batch and embedding dims to tiles."""
     b, _ = ids.shape

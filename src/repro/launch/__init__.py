@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launchers: production mesh, compile cache, train/serve drivers."""

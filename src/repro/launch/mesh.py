@@ -1,9 +1,8 @@
 """Production mesh construction.
 
 Defined as functions (never module-level constants) so importing this module
-never touches jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* first jax
-init, and tests/benches must keep seeing 1 device.
+never touches jax device state: tests and benches must keep seeing the
+devices their process was started with.
 
 Topology assumption (TPU v5e-style): 16x16 = 256 chips per pod, 2 pods via
 DCN.  Axis roles: ``model`` = fast ICI ring (TP/EP), ``data`` = second ICI

@@ -17,6 +17,7 @@ import numpy as np
 from repro.core.index import build_index
 from repro.core.query import QueryConfig
 from repro.graphs import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import PPRService, ServiceConfig
 from repro.serving.batching import BatchingConfig
 
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--top-k", type=int, default=50)
     args = ap.parse_args()
 
+    enable_compile_cache()
     g = synthetic.rmat(args.n_log2, avg_deg=10.0, seed=0)
     print(f"graph n={g.n} m={g.m}; building index R={args.r}")
     index = None

@@ -2,10 +2,8 @@
 
 This is the single place that knows how every architecture family maps onto
 train/serve steps, what its batch pytree looks like, and how to fabricate
-both ShapeDtypeStruct specs (dry-run) and concrete synthetic batches (smoke
-tests, examples).  ``launch/dryrun.py`` and the smoke tests consume the same
-:class:`StepBundle`, so "what compiles on 512 devices" and "what runs on
-CPU" can never drift apart.
+both ShapeDtypeStruct specs and concrete synthetic batches (smoke tests,
+examples).
 """
 
 from __future__ import annotations

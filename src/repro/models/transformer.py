@@ -332,9 +332,8 @@ def _moe_ffn_shardmap(cfg: TransformerConfig, p, x: jax.Array) -> Tuple[jax.Arra
         P(ash.model, None, ash.fsdp_axis),        # w_down
     )
     out_specs = (P(tok_axes, None), P())
-    from repro.compat import shard_map
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

@@ -6,8 +6,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def make_replicated_build_step(mesh, n: int, l: int):
     def local_fn(contrib):
@@ -15,7 +13,7 @@ def make_replicated_build_step(mesh, n: int, l: int):
         dense = jnp.zeros((n, l), jnp.float32) + jnp.sum(contrib)
         return dense
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P("model", None),),
         out_specs=P(None, None),

@@ -1,5 +1,5 @@
 """hbm-residency violation: a Pallas kernel that stages the whole CSR
-``col_idx`` array into VMEM (default BlockSpec, no ``pltpu.ANY``) — the
+``col_idx`` array into VMEM (default BlockSpec, no ``pl.ANY``) — the
 exact layout the DMA-gather rebuild removed."""
 
 import jax

@@ -1,7 +1,7 @@
 import os
 
-# Tests run on the single real CPU device; the 512-device dry-run sets its
-# own XLA_FLAGS in a subprocess (launch/dryrun.py) and must NOT leak here.
+# Tests run on the single real CPU device; the multi-device checks set
+# their own XLA_FLAGS in a subprocess and must NOT leak here.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 

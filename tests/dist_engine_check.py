@@ -148,7 +148,7 @@ def main():
     iidx = idx.indices.reshape(cfg.ep, cfg.n_shard, cfg.index_l)
 
     sources = jnp.asarray([0, 3, 7, 11, 19, 23, 31, 42], jnp.int32)
-    step = make_verd_tile_step(cfg, mesh)
+    step = make_verd_tile_step(cfg, mesh, kernel_interpret=True)
     with mesh:
         tv, ti = jax.jit(step)(slabs, sources, ivals, iidx)
 
@@ -172,7 +172,7 @@ def main():
         cfg_c = DistConfig(n=n_pad, ep=2, q_tile=8, t_iterations=2,
                            index_l=16, top_k=20, exchange="dense",
                            compress_k=32)
-    step_c = make_verd_tile_step(cfg_c, mesh)
+    step_c = make_verd_tile_step(cfg_c, mesh, kernel_interpret=True)
     with mesh:
         cv, ci = jax.jit(step_c)(slabs, sources, ivals, iidx)
     np.testing.assert_allclose(

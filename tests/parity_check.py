@@ -47,7 +47,7 @@ def densify_answers(vals, idx, n):
 
 
 def run_distributed(cfg, slabs, sources, ivals, iidx, mesh):
-    step = make_verd_tile_step(cfg, mesh)
+    step = make_verd_tile_step(cfg, mesh, kernel_interpret=True)
     with mesh:
         tv, ti = jax.jit(step)(slabs, sources, ivals, iidx)
     return densify_answers(tv, ti, cfg.n)

@@ -1,7 +1,6 @@
 """Per-arch smoke tests: reduced config, one real step per shape on CPU.
 
-Asserts output shapes and absence of NaNs for every (arch x shape) cell —
-the CPU-runnable counterpart of the 512-device dry-run (same StepBundle).
+Asserts output shapes and absence of NaNs for every (arch x shape) cell.
 """
 
 import jax

@@ -335,18 +335,24 @@ def test_hub_graph_sparse_query_streams_bounded(monkeypatch):
     eng = BatchQueryEngine(hub, None, cfg)
     # guard the guard: one-shot would be K*cap ~ 65k wide; the streamed
     # fold keeps live width at the stream target
-    seen = []
-    orig = verd_mod.gather_push_edges
+    seen = {}
 
-    def spy(fv, fi, *args, **kwargs):
-        out = orig(fv, fi, *args, **kwargs)
-        seen.append(out[0].shape[1])
-        return out
+    def spy(name):
+        orig = getattr(verd_mod, name)
 
-    monkeypatch.setattr(verd_mod, "gather_push_edges", spy)
+        def wrapped(*args, **kwargs):
+            out = orig(*args, **kwargs)
+            seen.setdefault(name, []).append(out[0].shape[1])
+            return out
+
+        monkeypatch.setattr(verd_mod, name, wrapped)
+
+    spy("gather_push_edges")
+    spy("gather_packed_edges")
     vals, idx = eng.query_topk(srcs)
-    assert seen, "sparse push never ran"
-    assert max(seen) < 16 * eng.degree_cap(), seen  # chunked, not one-shot
+    assert seen.get("gather_packed_edges"), f"push never streamed: {seen}"
+    widths = [w for ws in seen.values() for w in ws]
+    assert max(widths) < 16 * eng.degree_cap(), seen  # chunked, not one-shot
     dense_eng = BatchQueryEngine(
         hub, None, QueryConfig(mode="verd", top_k=8, frontier_path="dense")
     )

@@ -272,7 +272,7 @@ def test_sharded_push_kernel_matches_ref(q, k, shards, hub_split_degree, rng):
             hub_split_degree=hub_split_degree, q_tile=1, interpret=True,
         )
         ref_v, ref_i = ref.sharded_push_ref(
-            fv, fi, slabs.row_ptr[s], slabs.col_idx[s],
+            fv, fi, slabs.row_ptr[s], slabs.col_idx[s].reshape(-1),
             c=0.15, ep=shards, n_shard=ns, wire_k=ns,
         )
         np.testing.assert_allclose(
@@ -301,7 +301,7 @@ def test_sharded_push_truncated_wire_is_top_k(rng):
         q_tile=4, interpret=True,
     )
     full_v, full_i = ref.sharded_push_ref(
-        fv, fi, slabs.row_ptr[0], slabs.col_idx[0],
+        fv, fi, slabs.row_ptr[0], slabs.col_idx[0].reshape(-1),
         c=0.15, ep=2, n_shard=ns, wire_k=ns,
     )
     want = np.sort(np.asarray(full_v), axis=2)[:, :, ::-1][:, :, :wire_k]
@@ -349,7 +349,7 @@ def test_embedding_bag_wrapper_unaligned(rng):
 #
 # Two halves: (a) a mechanical memory contract — tracing each DMA kernel
 # and asserting that no CSR/index array enters as a whole-array VMEM block
-# (only `pltpu.ANY`/HBM refs + tile-sized VMEM blocks), (b) the boundary
+# (only `pl.ANY`/HBM refs + tile-sized VMEM blocks), (b) the boundary
 # cases the old resident-block kernels never exercised: ragged last q_tile,
 # k_out wider than the candidate set, empty frontiers, all-dangling rows,
 # single-row grids.
@@ -382,8 +382,8 @@ def test_frontier_push_memory_contract(rng, hub_split_degree):
     and every VMEM block is tile-sized (independent of n and m)."""
     from repro.core import verd as verd_mod
 
-    g, srcs, cap, fv, fi = _contract_fixture(rng)
-    q_tile, k_out = 8, 16
+    g, srcs, cap, fv, fi = _contract_fixture(rng, n=16384)
+    q_tile, k_out = 1, 16
     blocks = _pallas_block_specs(
         push_mod.frontier_push, fv, fi, srcs,
         g.row_ptr, g.out_deg, g.col_idx,
@@ -391,10 +391,11 @@ def test_frontier_push_memory_contract(rng, hub_split_degree):
         hub_split_degree=hub_split_degree, interpret=True,
     )
     h, s = verd_mod.resolve_hub_splits(cap, hub_split_degree)
-    budget = q_tile * fv.shape[1] * s * h + q_tile * max(fv.shape[1], k_out)
+    budget = push_mod.window_step_rows(q_tile * fv.shape[1] * s, h) * 128
     assert budget < g.m and budget < g.n  # the assertion below is meaningful
     _assert_hbm_contract(
-        blocks, hbm_shapes={(g.m,)}, vmem_budget=budget
+        blocks, hbm_shapes={push_mod.lane_rows_shape(g.m)},
+        vmem_budget=budget,
     )
     # and the CSR arrays specifically never appear as VMEM blocks
     for csr_shape in [(g.n + 1,), (g.n,), (g.m,)]:
@@ -407,13 +408,13 @@ def test_sharded_push_memory_contract(rng):
     from repro.core import verd as verd_mod
     from repro.core.distributed_engine import DistConfig, build_sharded_graph
 
-    g, _, cap, fv, fi = _contract_fixture(rng)
-    cfg = DistConfig(n=2048, ep=2, degree_cap=cap)
+    g, _, cap, fv, fi = _contract_fixture(rng, n=16384)
+    cfg = DistConfig(n=16384, ep=2, degree_cap=cap)
     slabs = build_sharded_graph(g, cfg)
     ns = cfg.n_shard
     fi_local = jnp.clip(fi, 0, ns - 1)
-    q_tile, wire_k = 4, 8
-    m_shard = slabs.col_idx.shape[1]
+    q_tile, wire_k = 1, 8
+    m_shard = slabs.col_idx[0].size     # the slab, stored as lane rows
     blocks = _pallas_block_specs(
         push_mod.sharded_frontier_push, fv, fi_local,
         slabs.row_ptr[0], slabs.col_idx[0],
@@ -421,9 +422,12 @@ def test_sharded_push_memory_contract(rng):
         q_tile=q_tile, interpret=True,
     )
     h, s = verd_mod.resolve_hub_splits(cap, 0)
-    budget = q_tile * fv.shape[1] * s * h + q_tile * 2 * wire_k
+    budget = push_mod.window_step_rows(q_tile * fv.shape[1] * s, h) * 128
     assert budget < m_shard and budget < ns
-    _assert_hbm_contract(blocks, hbm_shapes={(m_shard,)}, vmem_budget=budget)
+    _assert_hbm_contract(
+        blocks, hbm_shapes={slabs.col_idx.shape[1:]},
+        vmem_budget=budget,
+    )
 
 
 def test_index_combine_sparse_memory_contract(rng):
@@ -439,12 +443,13 @@ def test_index_combine_sparse_memory_contract(rng):
         comb_mod.index_combine_sparse, sv, si, fv, fi, vals, idx,
         k_out=k_out, q_tile=q_tile, interpret=True,
     )
-    budget = q_tile * k * l + q_tile * max(s_w, k, k_out) * 2
+    budget = comb_mod.row_step_rows(q_tile * k, l) * 128
     assert budget < n * l
-    _assert_hbm_contract(blocks, hbm_shapes={(n, l)}, vmem_budget=budget)
+    padded = comb_mod.padded_index_shape(n, l)
+    _assert_hbm_contract(blocks, hbm_shapes={padded}, vmem_budget=budget)
     # both [n, L] index arrays must be HBM refs
     assert sum(
-        1 for shape, space in blocks if shape == (n, l) and space == "any"
+        1 for shape, space in blocks if shape == padded and space == "any"
     ) == 2
 
 
@@ -567,7 +572,7 @@ def test_sharded_push_ragged_and_empty(rng):
         q_tile=4, interpret=True,
     )
     ref_v, ref_i = ref.sharded_push_ref(
-        fv, fi, slabs.row_ptr[0], slabs.col_idx[0],
+        fv, fi, slabs.row_ptr[0], slabs.col_idx[0].reshape(-1),
         c=0.15, ep=2, n_shard=ns, wire_k=ns,
     )
     np.testing.assert_allclose(
@@ -598,7 +603,7 @@ def test_sharded_push_wire_k_above_owner_support(rng):
         q_tile=4, interpret=True,
     )
     ref_v, ref_i = ref.sharded_push_ref(
-        fv, fi, slabs.row_ptr[0], slabs.col_idx[0],
+        fv, fi, slabs.row_ptr[0], slabs.col_idx[0].reshape(-1),
         c=0.15, ep=2, n_shard=ns, wire_k=wire_k,
     )
     np.testing.assert_allclose(
@@ -690,31 +695,107 @@ def test_frontier_push_window_clip_at_csr_end(rng, hub_split_degree):
     )
 
 
+# -- offsets past one call's SMEM: split over several pallas_calls ----------
+
+def _pallas_calls(fn, *args) -> int:
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return sum(e.primitive.name == "pallas_call" for e in eqns)
+
+
+def test_gather_windows_splits_past_smem_offsets(rng):
+    """More window pieces than one call's scalar prefetch holds: the
+    offsets split over several pallas_calls and the result is still the
+    plain gather (windows of two 128-wide pieces, capped step rows)."""
+    m, h = 4096, 200
+    col = jnp.asarray(rng.integers(0, 1000, m), jnp.int32)
+    starts = jnp.asarray(
+        rng.integers(0, m - h, push_mod.SMEM_OFFSETS // 2 + 300), jnp.int32)
+
+    def gather(c, st):
+        return push_mod.gather_windows(
+            push_mod.lane_rows(c), st, h=h, step_windows=1 << 16,
+            interpret=True)
+
+    assert _pallas_calls(gather, col, starts) >= 2
+    np.testing.assert_array_equal(
+        np.asarray(gather(col, starts)),
+        np.asarray(jnp.take(col, starts[:, None] + jnp.arange(h))))
+
+
+def test_gather_index_rows_splits_past_smem_offsets(rng):
+    """More touched rows than one call's scalar prefetch holds, from an
+    index whose width is not a multiple of 128."""
+    n, l = 600, 200
+    vals = jnp.asarray(rng.random((n, l)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, n, (n, l)), jnp.int32)
+    rows = jnp.asarray(
+        rng.integers(0, n, push_mod.SMEM_OFFSETS + 500), jnp.int32)
+
+    def gather(v, i, r):
+        return comb_mod.gather_index_rows(
+            v, i, r, step_rows=1 << 16, interpret=True)
+
+    assert _pallas_calls(gather, vals, idx, rows) >= 2
+    got_v, got_i = gather(vals, idx, rows)
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(vals[rows]))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(idx[rows]))
+
+
+def test_frontier_push_past_smem_offsets_matches_ref(rng):
+    """The fused push at a window count past one call's SMEM (Q * K * s >
+    SMEM_OFFSETS, hub-split sub-slots) still matches its oracle."""
+    from repro.core import frontier as F
+    from repro.core import verd as verd_mod
+
+    g = synthetic.erdos_renyi(2048, 6.0, seed=7)
+    cap = verd_mod.resolve_degree_cap(g)
+    q, k, split = 128, 256, 4
+    _, s = verd_mod.resolve_hub_splits(cap, split)
+    assert q * k * s > push_mod.SMEM_OFFSETS
+    srcs = jnp.asarray(rng.integers(0, g.n, q), jnp.int32)
+    f = F.SparseFrontier(
+        values=jnp.asarray(rng.random((q, k)), jnp.float32),
+        indices=jnp.asarray(rng.integers(0, g.n, (q, k)), jnp.int32),
+        k=k, n=g.n)
+    k_out = k * cap + 1                   # covers every row's support
+    got = ops.frontier_push(
+        f, g, srcs, c=0.15, degree_cap=cap, k_out=k_out,
+        hub_split_degree=split, interpret=True)
+    rv, ri = ref.frontier_push_ref(
+        f.values, f.indices, srcs, g.row_ptr, g.out_deg, g.col_idx,
+        c=0.15, degree_cap=cap, k_out=k_out)
+    np.testing.assert_allclose(
+        np.asarray(got.densify()),
+        np.asarray(F.SparseFrontier(
+            values=rv, indices=ri, k=k_out, n=g.n).densify()),
+        rtol=1e-5, atol=1e-6)
+
+
 # -- VMEM accounting + compiled-mode (real TPU) gates -----------------------
 
 def test_push_vmem_accounting_independent_of_graph_size():
     """HBM-resident per-step VMEM must not grow with n or m; the legacy
     accounting (whole-array CSR blocks) must."""
-    small = push_mod.vmem_bytes(8, 64, 32, degree_cap=16)
-    assert small == push_mod.vmem_bytes(8, 64, 32, degree_cap=16)
+    small = push_mod.vmem_bytes(8, 64, degree_cap=16)
+    assert small == push_mod.vmem_bytes(8, 64, degree_cap=16)
     legacy_small = push_mod.vmem_bytes_legacy(
-        8, 64, 32, n=1_000, m=8_000, degree_cap=16
+        8, 64, n=1_000, m=8_000, degree_cap=16
     )
     legacy_big = push_mod.vmem_bytes_legacy(
-        8, 64, 32, n=1_000_000, m=8_000_000, degree_cap=16
+        8, 64, n=1_000_000, m=8_000_000, degree_cap=16
     )
     assert legacy_big > legacy_small > small
-    # hub splitting bounds the scratch: splitting a cap-4096 gather into
-    # width-64 sub-slots leaves the byte count unchanged (s*h == cap) but a
-    # truncating split never grows it
+    # the per-step block is capped: a cap-4096 gather, split into width-64
+    # sub-slots or not, stays within the 16 MiB scoped VMEM
     assert push_mod.vmem_bytes(
-        8, 64, 32, degree_cap=4096, hub_split_degree=64
-    ) == push_mod.vmem_bytes(8, 64, 32, degree_cap=4096)
-    comb_small = comb_mod.sparse_vmem_bytes(8, 64, 16, 32, 32)
+        8, 64, degree_cap=4096, hub_split_degree=64
+    ) == push_mod.vmem_bytes(8, 64, degree_cap=4096) < 16 * 1024 * 1024
+    comb_small = comb_mod.sparse_vmem_bytes(8, 64, 32)
     comb_legacy = comb_mod.sparse_vmem_bytes_legacy(
-        8, 64, 16, 32, 32, n=1_000_000
+        8, 64, 32, n=1_000_000
     )
     assert comb_legacy > comb_small
+    assert comb_mod.sparse_vmem_bytes(8, 512, 667) < 16 * 1024 * 1024
 
 
 @pytest.mark.tpu
@@ -761,7 +842,7 @@ def test_sharded_push_compiled(rng):
         interpret=False,
     )
     ref_v, ref_i = ref.sharded_push_ref(
-        fv, fi, slabs.row_ptr[0], slabs.col_idx[0],
+        fv, fi, slabs.row_ptr[0], slabs.col_idx[0].reshape(-1),
         c=0.15, ep=2, n_shard=ns, wire_k=ns,
     )
     np.testing.assert_allclose(
@@ -828,7 +909,9 @@ def test_walk_step_wrapper_pads_ragged(w, rng):
     """W not a multiple of w_tile: ops.walk_step pads and slices."""
     g, cur, src, u = _walk_fixture(rng, w=max(w, 1))
     cur, src, u = cur[:w], src[:w], u[:w]
-    got = ops.walk_step(cur, src, u, g.row_ptr, g.out_deg, g.col_idx)
+    got = ops.walk_step(
+        cur, src, u, g.row_ptr, g.out_deg, g.col_idx, interpret=True
+    )
     want = ref.walk_step_ref(cur, src, u, g.row_ptr, g.out_deg, g.col_idx)
     assert got.shape == (w,)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -839,7 +922,9 @@ def test_walk_step_wrapper_keeps_2d_shape(rng):
     cur2 = cur.reshape(8, 12)
     src2 = src.reshape(8, 12)
     u2 = u.reshape(8, 12)
-    got = ops.walk_step(cur2, src2, u2, g.row_ptr, g.out_deg, g.col_idx)
+    got = ops.walk_step(
+        cur2, src2, u2, g.row_ptr, g.out_deg, g.col_idx, interpret=True
+    )
     assert got.shape == (8, 12)
     want = ref.walk_step_ref(cur, src, u, g.row_ptr, g.out_deg, g.col_idx)
     np.testing.assert_array_equal(np.asarray(got).reshape(-1),
@@ -856,7 +941,7 @@ def test_walk_step_dangling_rows_jump_home(rng):
     src = jnp.asarray([1, 2, 4, 0] * 32, jnp.int32)
     u = jnp.asarray(np.linspace(0, 0.999, 128), jnp.float32)
     got = np.asarray(ops.walk_step(
-        cur, src, u, g.row_ptr, g.out_deg, g.col_idx
+        cur, src, u, g.row_ptr, g.out_deg, g.col_idx, interpret=True
     ))
     np.testing.assert_array_equal(got[0::4], 1)   # dangling -> source
     np.testing.assert_array_equal(got[1::4], 2)
@@ -874,7 +959,7 @@ def test_walk_step_clip_at_csr_end(rng):
     src = jnp.zeros((128,), jnp.int32)
     u = jnp.full((128,), 0.999999, jnp.float32)   # samples the last edge
     got = np.asarray(ops.walk_step(
-        cur, src, u, g.row_ptr, g.out_deg, g.col_idx
+        cur, src, u, g.row_ptr, g.out_deg, g.col_idx, interpret=True
     ))
     np.testing.assert_array_equal(got, 0)
 
@@ -886,7 +971,9 @@ def test_walk_step_edgeless_fallback(rng):
     cur = jnp.asarray([0, 1, 2, 3], jnp.int32)
     src = jnp.asarray([3, 2, 1, 0], jnp.int32)
     u = jnp.zeros((4,), jnp.float32)
-    got = ops.walk_step(cur, src, u, g.row_ptr, g.out_deg, g.col_idx)
+    got = ops.walk_step(
+        cur, src, u, g.row_ptr, g.out_deg, g.col_idx, interpret=True
+    )
     np.testing.assert_array_equal(np.asarray(got), [3, 2, 1, 0])
 
 
@@ -898,9 +985,12 @@ def test_walk_step_memory_contract(rng):
         walk_mod.walk_step, cur, src, u, g.row_ptr, g.out_deg, g.col_idx,
         w_tile=128, interpret=True,
     )
-    budget = walk_mod.vmem_bytes(128) // 4 + 128  # elements, not bytes
+    budget = walk_mod.step_walks(128)  # one packed int32 output lane each
     assert budget < g.m and budget < g.n
-    _assert_hbm_contract(blocks, hbm_shapes={(g.m,)}, vmem_budget=budget)
+    _assert_hbm_contract(
+        blocks, hbm_shapes={push_mod.lane_rows_shape(g.m)},
+        vmem_budget=budget,
+    )
     for csr_shape in [(g.n + 1,), (g.n,), (g.m,)]:
         assert all(
             space == "any" for shape, space in blocks if shape == csr_shape
@@ -908,8 +998,8 @@ def test_walk_step_memory_contract(rng):
 
 
 def test_walk_step_vmem_accounting():
-    assert walk_mod.vmem_bytes(128) < 16 * 1024
-    assert walk_mod.vmem_bytes(128) == walk_mod.vmem_bytes(128)
+    assert walk_mod.vmem_bytes(128) < 64 * 1024
+    assert walk_mod.vmem_bytes(128) == walk_mod.vmem_bytes(1024)
 
 
 @pytest.mark.tpu

@@ -54,7 +54,7 @@ def test_one_shard_matches_single_device_sparse(setup, hub_split_degree):
     iidx = idx.indices.reshape(1, cfg.n_shard, cfg.index_l)
     sources = jnp.asarray([0, 5, 17, 42], jnp.int32)
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    step = make_verd_tile_step(cfg, mesh)
+    step = make_verd_tile_step(cfg, mesh, kernel_interpret=True)
     with mesh:
         tv, ti = jax.jit(step)(slabs, sources, ivals, iidx)
     got = _densify(tv, ti, n_pad)
@@ -88,7 +88,7 @@ def test_one_shard_truncated_wire_bounded(setup):
                      ("trunc", dict(frontier_k=4, wire_k=4))]:
         cfg = DistConfig(**base, **kw)
         slabs = build_sharded_graph(g, cfg)
-        step = make_verd_tile_step(cfg, mesh)
+        step = make_verd_tile_step(cfg, mesh, kernel_interpret=True)
         with mesh:
             tv, ti = jax.jit(step)(slabs, sources, ivals, iidx)
         outs[name] = _densify(tv, ti, n_pad)
@@ -137,7 +137,7 @@ def test_engine_routes_through_fused_kernel(setup):
     iidx = idx.indices.reshape(1, cfg.n_shard, cfg.index_l)
     sources = jnp.asarray([0, 5, 17, 42], jnp.int32)
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    step = make_verd_tile_step(cfg, mesh)
+    step = make_verd_tile_step(cfg, mesh, kernel_interpret=True)
     kernel_ops.reset_kernel_invocations()
     with mesh:
         tv, ti = jax.jit(step)(slabs, sources, ivals, iidx)
@@ -148,15 +148,6 @@ def test_engine_routes_through_fused_kernel(setup):
     oracle = np.asarray(verd_mod.verd_query(g, sources, idx_small, t=2))
     got = _densify(tv, ti, n_pad)
     assert np.abs(got[:, : g.n] - oracle).sum(axis=1).max() <= 1e-5
-
-
-def test_kernel_interpret_resolution():
-    """Off-TPU the engine defaults the fused kernel to interpret mode; an
-    explicit setting wins either way."""
-    assert DistConfig(n=64, ep=1).resolved_kernel_interpret is True  # CPU here
-    assert DistConfig(
-        n=64, ep=1, kernel_interpret=False
-    ).resolved_kernel_interpret is False
 
 
 def test_sparse_exchange_requires_degree_cap():

@@ -234,6 +234,27 @@ def test_repair_matches_rebuild_sharded_padded():
     assert jnp.array_equal(m2.touch.bits, ref_stats["touch"])
 
 
+def test_replace_rows_keeps_mesh_sharding():
+    """replace_rows on a sharded index (from a mesh built with the default
+    Explicit axes) scatters and stays on the index's mesh sharding."""
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    g = synthetic.erdos_renyi(100, 3.0, seed=5)
+    index, _ = build_index_sharded(
+        g, r=4, l=8, key=jax.random.PRNGKey(0), mesh=mesh, source_batch=16,
+        c=0.25, respawn=True)
+    rows = np.asarray([3, 40, 99])
+    new_v = np.full((3, 8), 0.5, np.float32)
+    new_i = np.tile(np.arange(8, dtype=np.int32), (3, 1))
+    out = index.replace_rows(rows, new_v, new_i)
+    assert out.values.sharding == index.values.sharding
+    assert out.indices.sharding == index.indices.sharding
+    want_v = np.asarray(index.values).copy()
+    want_i = np.asarray(index.indices).copy()
+    want_v[rows], want_i[rows] = new_v, new_i
+    np.testing.assert_array_equal(np.asarray(out.values), want_v)
+    np.testing.assert_array_equal(np.asarray(out.indices), want_i)
+
+
 def test_apply_updates_noop_returns_same_index(key):
     g = synthetic.erdos_renyi(64, 3.0, seed=1)
     m, _ = updates.build_maintainable_index(

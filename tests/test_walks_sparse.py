@@ -301,7 +301,7 @@ def test_kernel_routed_engine_is_bitwise_identical(key):
     sources = jnp.asarray([0, 5, 9], jnp.int32)
     a = walks.simulate_walks_sparse(g, sources, 64, key, l=64)
     b = walks.simulate_walks_sparse(
-        g, sources, 64, key, l=64, use_kernel=True
+        g, sources, 64, key, l=64, use_kernel=True, kernel_interpret=True
     )
     for x, y in (
         (a.fp.values, b.fp.values), (a.fp.indices, b.fp.indices),
