@@ -10,9 +10,8 @@ knee throughput >= 2x that baseline at the n=100k / K=512 reference point
 (same reference as bench_query's sparse sweep).
 
 Warmup dispatches cover every padded jit shape the batcher can form
-(``min_pad .. max_batch`` powers of two) before any measurement, and the
-harness additionally reports ``wall_s_excl_first_batch`` so trajectories
-are never dominated by compile time.
+(``min_pad .. max_batch`` powers of two) before any measurement, so
+trajectories are never dominated by compile time.
 """
 
 from __future__ import annotations
@@ -71,7 +70,6 @@ def _point(stats: dict) -> dict:
     """The per-measurement slice of stats the JSON trajectory keeps."""
     return dict(
         offered_qps=stats["offered_qps"], qps=stats["qps"],
-        qps_excl_first_batch=stats["qps_excl_first_batch"],
         latency_p50=stats["latency_p50"], latency_p99=stats["latency_p99"],
         mean_latency=stats["mean_latency"], served=stats["served"],
         batches=stats["batches"], pad_fraction=stats["pad_fraction"],
@@ -118,8 +116,7 @@ def run(fast: bool = False) -> dict:
         _warmup(svc, p)
         _, stats = run_closed_loop(svc, workload)
         # the service is warm (all jit shapes compiled by _warmup), so the
-        # plain wall-clock qps is the honest capacity; excl_first_batch
-        # only matters on cold services
+        # plain wall-clock qps is the honest capacity
         capacity[name] = stats["qps"]
         services[name] = svc
         out["closed_loop"][name] = _point(stats)

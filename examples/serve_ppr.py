@@ -49,8 +49,7 @@ def main():
     workload = rng.integers(0, g.n, size=2000)
     answers, stats = svc.run_closed_loop(workload)
     print(f"closed loop: served {stats['served']:.0f} requests in "
-          f"{stats['wall_s']:.2f}s ({stats['qps']:.0f} q/s, "
-          f"{stats['qps_excl_first_batch']:.0f} q/s excl. first batch), "
+          f"{stats['wall_s']:.2f}s ({stats['qps']:.0f} q/s), "
           f"{stats['batches']:.0f} batches, depth={stats['pipeline_depth']}")
     print(f"  latency mean={stats['mean_latency'] * 1e3:.1f}ms "
           f"p99={stats['latency_p99'] * 1e3:.1f}ms")

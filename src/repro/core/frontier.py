@@ -187,13 +187,15 @@ def fold_topk(
     Returns ``(values, indices, dropped)`` where ``dropped`` is the per-row
     mass truncated away by *this* fold — the exact error-budget increment a
     sketch consumer accumulates (dropped mass only ever leaves, so the
-    running total bounds the sketch's L1 understatement).
+    running total bounds the sketch's L1 understatement).  Its device work
+    carries the named scope ``ppr.fold``.
     """
-    cand_v = jnp.concatenate([run_v, add_v], axis=1)
-    cand_i = jnp.concatenate([run_i, add_i], axis=1)
-    out_v, out_i = compact_arrays(cand_v, cand_i, k)
-    dropped = jnp.sum(cand_v, axis=1) - jnp.sum(out_v, axis=1)
-    return out_v, out_i, jnp.maximum(dropped, 0.0)
+    with jax.named_scope("ppr.fold"):
+        cand_v = jnp.concatenate([run_v, add_v], axis=1)
+        cand_i = jnp.concatenate([run_i, add_i], axis=1)
+        out_v, out_i = compact_arrays(cand_v, cand_i, k)
+        dropped = jnp.sum(cand_v, axis=1) - jnp.sum(out_v, axis=1)
+        return out_v, out_i, jnp.maximum(dropped, 0.0)
 
 
 def merge_sketch_parts(

@@ -24,6 +24,7 @@ from typing import Iterable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType, NamedSharding
 
 from repro.core import frontier as frontier_mod
@@ -115,17 +116,19 @@ def normalize_sketch_to_index_rows(
     descending, ``moves`` the MCFP denominator, ``dropped_counts`` the
     count-domain dropped-mass ledger.  Returns ``(vals, idxs, kept,
     dropped)`` in estimate units, ``vals/idxs`` sliced to width ``l``.
+    Its device work carries the named scope ``ppr.topl``.
     """
-    inv_moves = 1.0 / jnp.maximum(moves[:, None], 1.0)
-    est_v = fp_v * inv_moves                          # sorted descending
-    vals, idxs = est_v[:, :l], fp_i[:, :l]
-    idxs = jnp.where(vals > 0, idxs, 0)
-    kept = jnp.sum(vals, axis=1)
-    dropped = (
-        jnp.sum(est_v[:, l:], axis=1)
-        + dropped_counts * inv_moves[:, 0]
-    )
-    return vals, idxs, kept, dropped
+    with jax.named_scope("ppr.topl"):
+        inv_moves = 1.0 / jnp.maximum(moves[:, None], 1.0)
+        est_v = fp_v * inv_moves                      # sorted descending
+        vals, idxs = est_v[:, :l], fp_i[:, :l]
+        idxs = jnp.where(vals > 0, idxs, 0)
+        kept = jnp.sum(vals, axis=1)
+        dropped = (
+            jnp.sum(est_v[:, l:], axis=1)
+            + dropped_counts * inv_moves[:, 0]
+        )
+        return vals, idxs, kept, dropped
 
 
 @functools.partial(
@@ -198,10 +201,11 @@ def sparse_chunk_estimates(
             dropped = dropped + counts.fp_dropped
             if touch_bits:
                 touch = counts.touch if touch is None else touch | counts.touch
-        fp_v, fp_i, dropped = frontier_mod.merge_sketch_parts(
-            jnp.concatenate(vs, axis=1), jnp.concatenate(is_, axis=1),
-            dropped, sketch_l,
-        )
+        with jax.named_scope("ppr.sketch_fold"):
+            fp_v, fp_i, dropped = frontier_mod.merge_sketch_parts(
+                jnp.concatenate(vs, axis=1), jnp.concatenate(is_, axis=1),
+                dropped, sketch_l,
+            )
     else:
         counts = simulate_walks_sparse(
             graph, chunk_sources, r, key, l=sketch_l, ep_l=0, c=c,
@@ -282,10 +286,11 @@ def build_index(
         sources = np.arange(n, dtype=np.int32)
         duplicate_sources = 0
     else:
-        sources = np.asarray(sources, dtype=np.int32)
-        unique_sources = np.unique(sources)  # sorted unique set
-        duplicate_sources = len(sources) - len(unique_sources)
-        sources = unique_sources
+        with TraceAnnotation("ppr.build.prepare"):
+            sources = np.asarray(sources, dtype=np.int32)
+            unique_sources = np.unique(sources)  # sorted unique set
+            duplicate_sources = len(sources) - len(unique_sources)
+            sources = unique_sources
     if engine == "sparse":
         index, stats = _build_index_sparse(
             graph, r, l, key, c=c, max_steps=max_steps,
@@ -403,6 +408,18 @@ def _complete_stats(extra: dict, tree: dict, touch_bits: int) -> dict:
     return stats
 
 
+@functools.partial(jax.jit, static_argnames=("n",))
+def _assemble_rows(parts, rows, *, n: int) -> jax.Array:
+    """The index rows of a sweep's chunks (named scope
+    ``ppr.index_assemble``): concatenated in order, and for a subset build
+    (``rows`` the sorted source ids) scattered into ``n`` zero rows."""
+    with jax.named_scope("ppr.index_assemble"):
+        out = jnp.concatenate(parts, axis=0)
+        if rows is None:
+            return out
+        return jnp.zeros((n,) + out.shape[1:], out.dtype).at[rows].set(out)
+
+
 def sketch_width(n: int, l: int) -> int:
     """Visit-count sketch width of the sparse builders: headroom over the
     index width keeps the running top-L honest (entries near rank ``l``
@@ -445,13 +462,14 @@ def _build_index_sparse(
     n = graph.n
     l = min(l, n)
     sketch_l = sketch_width(n, l)
-    sources = np.asarray(sources, dtype=np.int32)
-    n_src = len(sources)
-    pad_rows = (-n_src) % source_batch
-    padded = np.concatenate(
-        [sources, np.zeros(pad_rows, np.int32)]
-    ) if pad_rows else sources
-    n_chunks = len(padded) // source_batch
+    with TraceAnnotation("ppr.build.prepare"):
+        sources = np.asarray(sources, dtype=np.int32)
+        n_src = len(sources)
+        pad_rows = (-n_src) % source_batch
+        padded = np.concatenate(
+            [sources, np.zeros(pad_rows, np.int32)]
+        ) if pad_rows else sources
+        n_chunks = len(padded) // source_batch
 
     ckpt = _make_build_checkpointer(
         checkpoint_dir, checkpoint_every, checkpoint_keep, fault_plan)
@@ -518,84 +536,78 @@ def _build_index_sparse(
     for ci in range(start_chunk, n_chunks):
         if fault_plan is not None:
             fault_plan.chunk_boundary(ci)
-        i = ci * source_batch
-        chunk = jnp.asarray(padded[i : i + source_batch])
-        real = min(source_batch, n_src - i)
-        sub_key = jax.random.fold_in(key, i)
-        out = sparse_chunk_estimates(
-            graph, chunk, sub_key, r=r, l=l, sketch_l=sketch_l, c=c,
-            max_steps=max_steps, compact_every=compact_every,
-            r_splits=r_splits, respawn=respawn, touch_bits=touch_bits,
-        )
-        vals, idxs, kept, dropped = out[:4]
-        # device-level slicing of the ragged tail: no host sync, pad rows
-        # never reach the index or the stats
-        vals_chunks.append(vals[:real])
-        idxs_chunks.append(idxs[:real])
-        ledger.append(jnp.sum(kept[:real]), jnp.sum(dropped[:real]))
-        if touch_bits:
-            touch_chunks.append(out[4][:real])
-        done = ci + 1
-        if ckpt is not None and done < n_chunks \
-                and done % checkpoint_every == 0:
-            commit_partial(done)
-
-    touch = None
-    if not n_src:  # empty sources: a valid all-zero index
-        values = jnp.zeros((n, l), jnp.float32)
-        indices = jnp.zeros((n, l), jnp.int32)
-        if touch_bits:
-            touch = jnp.zeros((n, touch_bits), bool)
-    elif n_src == n and np.array_equal(
-        sources, np.arange(n, dtype=np.int32)
-    ):
-        values = jnp.concatenate(vals_chunks, axis=0)
-        indices = jnp.concatenate(idxs_chunks, axis=0)
-        if touch_bits:
-            touch = jnp.concatenate(touch_chunks, axis=0)
-    else:  # subset build: one scatter into the zero index
-        src_dev = jnp.asarray(sources)
-        values = jnp.zeros((n, l), jnp.float32).at[src_dev].set(
-            jnp.concatenate(vals_chunks, axis=0)
-        )
-        indices = jnp.zeros((n, l), jnp.int32).at[src_dev].set(
-            jnp.concatenate(idxs_chunks, axis=0)
-        )
-        if touch_bits:
-            touch = jnp.zeros((n, touch_bits), bool).at[src_dev].set(
-                jnp.concatenate(touch_chunks, axis=0)
+        with TraceAnnotation("ppr.build.chunk", chunk=ci):
+            i = ci * source_batch
+            chunk = jnp.asarray(padded[i : i + source_batch])
+            real = min(source_batch, n_src - i)
+            sub_key = jax.random.fold_in(key, i)
+            out = sparse_chunk_estimates(
+                graph, chunk, sub_key, r=r, l=l, sketch_l=sketch_l, c=c,
+                max_steps=max_steps, compact_every=compact_every,
+                r_splits=r_splits, respawn=respawn, touch_bits=touch_bits,
             )
-    kept, dropped = ledger.totals()
-    stats = dict(
-        r=r,
-        l=l,
-        engine="sparse",
-        sketch_l=sketch_l,
-        r_splits=r_splits,
-        respawn=bool(respawn),
-        source_batch=source_batch,
-        pad_rows=pad_rows,
-        pad_fraction=pad_rows / max(n_src + pad_rows, 1),
-        kept_mass=kept,
-        dropped_mass=dropped,
-        drop_fraction=dropped / max(kept + dropped, 1e-12),
-        nbytes=n * l * 8,
-    )
-    if ckpt is not None:
-        stats["checkpoint_commits"] = commits
-        stats["resumed_at_chunk"] = start_chunk
-        kept_arr, dropped_arr = ledger.export()
-        tree = dict(
-            vals=np.asarray(values), idxs=np.asarray(indices),
-            kept=kept_arr, dropped=dropped_arr,
+            vals, idxs, kept, dropped = out[:4]
+            # device-level slicing of the ragged tail: no host sync, pad
+            # rows never reach the index or the stats
+            vals_chunks.append(vals[:real])
+            idxs_chunks.append(idxs[:real])
+            ledger.append(jnp.sum(kept[:real]), jnp.sum(dropped[:real]))
+            if touch_bits:
+                touch_chunks.append(out[4][:real])
+            done = ci + 1
+            if ckpt is not None and done < n_chunks \
+                    and done % checkpoint_every == 0:
+                commit_partial(done)
+
+    with TraceAnnotation("ppr.build.assemble"):
+        touch = None
+        if not n_src:  # empty sources: a valid all-zero index
+            values = jnp.zeros((n, l), jnp.float32)
+            indices = jnp.zeros((n, l), jnp.int32)
+            if touch_bits:
+                touch = jnp.zeros((n, touch_bits), bool)
+        else:
+            # a full sweep in order is the index itself; a subset build
+            # scatters its rows into the zero index
+            rows = None if n_src == n and np.array_equal(
+                sources, np.arange(n, dtype=np.int32)
+            ) else jnp.asarray(sources)
+            values = _assemble_rows(vals_chunks, rows, n=n)
+            indices = _assemble_rows(idxs_chunks, rows, n=n)
+            if touch_bits:
+                touch = _assemble_rows(touch_chunks, rows, n=n)
+    with TraceAnnotation("ppr.build.finish"):
+        kept, dropped = ledger.totals()
+        stats = dict(
+            r=r,
+            l=l,
+            engine="sparse",
+            sketch_l=sketch_l,
+            r_splits=r_splits,
+            respawn=bool(respawn),
+            source_batch=source_batch,
+            pad_rows=pad_rows,
+            pad_fraction=pad_rows / max(n_src + pad_rows, 1),
+            kept_mass=kept,
+            dropped_mass=dropped,
+            drop_fraction=dropped / max(kept + dropped, 1e-12),
+            nbytes=n * l * 8,
         )
-        if touch_bits:
-            tree["touch"] = np.asarray(touch)
-        ckpt.save(n_chunks, tree, dict(
-            signature=signature, complete=True,
-            next_chunk=n_chunks, n_chunks=n_chunks,
-            stats={k: v for k, v in stats.items() if k != "touch"},
-        ))
+        if ckpt is not None:
+            stats["checkpoint_commits"] = commits
+            stats["resumed_at_chunk"] = start_chunk
+            kept_arr, dropped_arr = ledger.export()
+            tree = dict(
+                vals=np.asarray(values), idxs=np.asarray(indices),
+                kept=kept_arr, dropped=dropped_arr,
+            )
+            if touch_bits:
+                tree["touch"] = np.asarray(touch)
+            ckpt.save(n_chunks, tree, dict(
+                signature=signature, complete=True,
+                next_chunk=n_chunks, n_chunks=n_chunks,
+                stats={k: v for k, v in stats.items() if k != "touch"},
+            ))
     if touch_bits:
         stats["touch"] = touch
         stats["touch_bits"] = touch_bits

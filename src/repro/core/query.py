@@ -527,8 +527,9 @@ def _fused_topk_impl(
             dense = pi_mod.power_iteration(graph, sources, n_iter=pi_iterations, c=c)
         else:
             raise ValueError(f"unknown mode {mode!r}")
-        vals, idx = jax.lax.top_k(dense, top_k)
-        idx = idx.astype(jnp.int32)
+        with jax.named_scope("ppr.topk"):
+            vals, idx = jax.lax.top_k(dense, top_k)
+            idx = idx.astype(jnp.int32)
     # same static-shape width contract as query_topk
     assert vals.shape[-1] == top_k and idx.shape[-1] == top_k, (
         vals.shape, idx.shape, top_k,

@@ -97,14 +97,6 @@ def verd_iterate(
     combination and dangling mass restarts at the seed distribution.
     """
     q = sources.shape[0]
-    if seed_weights is None:
-        f = jnp.zeros((q, graph.n), dtype=jnp.float32)
-        f = f.at[jnp.arange(q), sources].set(1.0)
-    else:
-        # .add, not .set: duplicate seeds within a row sum their weights
-        f = jnp.zeros((q, graph.n), dtype=jnp.float32)
-        f = f.at[jnp.arange(q)[:, None], sources].add(seed_weights)
-    s = jnp.zeros_like(f)
 
     def body(carry, _):
         s, f = carry
@@ -119,7 +111,15 @@ def verd_iterate(
             f = jnp.where(f >= threshold, f, 0.0)
         return (s, f), ()
 
-    (s, f), _ = jax.lax.scan(body, (s, f), None, length=t)
+    with jax.named_scope("ppr.push"):
+        f = jnp.zeros((q, graph.n), dtype=jnp.float32)
+        if seed_weights is None:
+            f = f.at[jnp.arange(q), sources].set(1.0)
+        else:
+            # .add, not .set: duplicate seeds within a row sum their weights
+            f = f.at[jnp.arange(q)[:, None], sources].add(seed_weights)
+        s = jnp.zeros_like(f)
+        (s, f), _ = jax.lax.scan(body, (s, f), None, length=t)
     return s, f
 
 
@@ -136,29 +136,31 @@ def combine_with_index(
     stays bounded; the Pallas ``index_combine`` kernel is the fused
     equivalent.
     """
-    q, n = f.shape
-    l = index.l
-    n_chunks = (n + vertex_chunk - 1) // vertex_chunk
-    pad_n = n_chunks * vertex_chunk
-    # a sharded/padded index may carry extra all-zero rows (index.n >= n);
-    # the dense frontier can only touch the first n, so slice before padding
-    vals = jnp.pad(index.values[:n], ((0, pad_n - n), (0, 0)))
-    idxs = jnp.pad(index.indices[:n], ((0, pad_n - n), (0, 0)))
-    f_pad = jnp.pad(f, ((0, 0), (0, pad_n - n)))
-    vals = vals.reshape(n_chunks, vertex_chunk, l)
-    idxs = idxs.reshape(n_chunks, vertex_chunk, l)
-    f_chunks = f_pad.reshape(q, n_chunks, vertex_chunk).transpose(1, 0, 2)
+    with jax.named_scope("ppr.combine"):
+        q, n = f.shape
+        l = index.l
+        n_chunks = (n + vertex_chunk - 1) // vertex_chunk
+        pad_n = n_chunks * vertex_chunk
+        # a sharded/padded index may carry extra all-zero rows (index.n >=
+        # n); the dense frontier can only touch the first n, so slice before
+        # padding
+        vals = jnp.pad(index.values[:n], ((0, pad_n - n), (0, 0)))
+        idxs = jnp.pad(index.indices[:n], ((0, pad_n - n), (0, 0)))
+        f_pad = jnp.pad(f, ((0, 0), (0, pad_n - n)))
+        vals = vals.reshape(n_chunks, vertex_chunk, l)
+        idxs = idxs.reshape(n_chunks, vertex_chunk, l)
+        f_chunks = f_pad.reshape(q, n_chunks, vertex_chunk).transpose(1, 0, 2)
 
-    def body(acc, args):
-        v, ix, fc = args  # [chunk, L], [chunk, L], [Q, chunk]
-        contrib = fc[:, :, None] * v[None, :, :]      # [Q, chunk, L]
-        acc = acc.at[:, ix.reshape(-1)].add(
-            contrib.reshape(q, -1)
-        )
-        return acc, ()
+        def body(acc, args):
+            v, ix, fc = args  # [chunk, L], [chunk, L], [Q, chunk]
+            contrib = fc[:, :, None] * v[None, :, :]      # [Q, chunk, L]
+            acc = acc.at[:, ix.reshape(-1)].add(
+                contrib.reshape(q, -1)
+            )
+            return acc, ()
 
-    out, _ = jax.lax.scan(body, s, (vals, idxs, f_chunks))
-    return out
+        out, _ = jax.lax.scan(body, s, (vals, idxs, f_chunks))
+        return out
 
 
 def verd_query(
@@ -388,14 +390,15 @@ def sparse_push_candidates(
     changes only the gather geometry (hub rows split across sub-slots), not
     the pushed mass.
     """
-    if graph.m == 0:  # every vertex dangling: all mass returns to the seeds
-        dm = jnp.sum(fv, axis=1)
-        return dangling_seed_candidates(dm, sources, seed_weights, c=c)
-    return gather_push_candidates(
-        fv, fi, sources, graph.row_ptr, graph.out_deg, graph.col_idx,
-        c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
-        seed_weights=seed_weights,
-    )
+    with jax.named_scope("ppr.push.gather"):
+        if graph.m == 0:  # every vertex dangling: all mass returns home
+            dm = jnp.sum(fv, axis=1)
+            return dangling_seed_candidates(dm, sources, seed_weights, c=c)
+        return gather_push_candidates(
+            fv, fi, sources, graph.row_ptr, graph.out_deg, graph.col_idx,
+            c=c, degree_cap=degree_cap, hub_split_degree=hub_split_degree,
+            seed_weights=seed_weights,
+        )
 
 
 def gather_packed_edges(
@@ -421,22 +424,24 @@ def gather_packed_edges(
     grouping, as :func:`gather_push_edges`.
     """
     q, k = fv.shape
-    e = first + jnp.arange(lanes, dtype=jnp.int32)
-    slot = jax.vmap(
-        lambda row: jnp.searchsorted(row, e, side="right")
-    )(ends).astype(jnp.int32)
-    valid = e[None, :] < ends[:, -1:]
-    slot = jnp.minimum(slot, k - 1)
-    before = jnp.where(
-        slot > 0,
-        jnp.take_along_axis(ends, jnp.maximum(slot - 1, 0), axis=1), 0,
-    )
-    at = lambda x: jnp.take_along_axis(x, slot, axis=1)
-    m = col_idx.shape[0]
-    nbrs = jnp.take(col_idx, jnp.clip(at(start) + e[None, :] - before, 0, m - 1))
-    inv = 1.0 / jnp.maximum(at(deg).astype(jnp.float32), 1.0)
-    push_v = jnp.where(valid, (1.0 - c) * at(fv) * inv, 0.0)
-    return push_v, jnp.where(valid, nbrs, 0)
+    with jax.named_scope("ppr.push.gather"):
+        e = first + jnp.arange(lanes, dtype=jnp.int32)
+        slot = jax.vmap(
+            lambda row: jnp.searchsorted(row, e, side="right")
+        )(ends).astype(jnp.int32)
+        valid = e[None, :] < ends[:, -1:]
+        slot = jnp.minimum(slot, k - 1)
+        before = jnp.where(
+            slot > 0,
+            jnp.take_along_axis(ends, jnp.maximum(slot - 1, 0), axis=1), 0,
+        )
+        at = lambda x: jnp.take_along_axis(x, slot, axis=1)
+        m = col_idx.shape[0]
+        nbrs = jnp.take(
+            col_idx, jnp.clip(at(start) + e[None, :] - before, 0, m - 1))
+        inv = 1.0 / jnp.maximum(at(deg).astype(jnp.float32), 1.0)
+        push_v = jnp.where(valid, (1.0 - c) * at(fv) * inv, 0.0)
+        return push_v, jnp.where(valid, nbrs, 0)
 
 
 def sparse_push_compact(
@@ -453,7 +458,9 @@ def sparse_push_compact(
     stream_width: int = 0,
     seed_weights: Optional[jax.Array] = None,
 ) -> frontier.SparseFrontier:
-    """One VERD push + compaction with bounded live candidate width.
+    """One VERD push + compaction with bounded live candidate width, under
+    the named scope ``ppr.push`` (the candidate gather under
+    ``ppr.push.gather``, each merge into the frontier under ``ppr.fold``).
 
     Semantically :func:`sparse_push_candidates` followed by
     :func:`frontier.compact`, but when the one-shot candidate tensor
@@ -471,59 +478,65 @@ def sparse_push_compact(
     dropped mass.  ``stream_width`` overrides the lane-block width
     (0 = auto: ``max(4 * k_out, 16384)``).
     """
-    q, k = fv.shape
-    m = graph.m
-    # seed-set queries emit S dangling candidates instead of 1 (see
-    # dangling_seed_candidates) — the one-shot width grows accordingly
-    s_width = 1 if seed_weights is None else int(seed_weights.shape[1])
-    if m == 0:  # all-dangling: S candidates per row, nothing to stream
-        cv, ci = sparse_push_candidates(
-            graph, fv, fi, sources, c=c, degree_cap=degree_cap,
-            seed_weights=seed_weights,
-        )
-        return frontier.compact(
-            cv, ci, min(k_out, cv.shape[1]), graph.n, threshold=threshold
-        )
-    cap = min(degree_cap, max(m, 1))
-    h, s = resolve_hub_splits(cap, hub_split_degree)
-    slot_w = s * h
-    out_w = min(k_out, k * slot_w + s_width)  # same width as one-shot path
-    lanes = stream_width if stream_width > 0 else max(4 * out_w, 16384)
-    if k * slot_w + s_width <= 2 * max(lanes, slot_w):  # narrow: one-shot
-        cv, ci = sparse_push_candidates(
-            graph, fv, fi, sources, c=c, degree_cap=degree_cap,
-            hub_split_degree=hub_split_degree, seed_weights=seed_weights,
-        )
-        return frontier.compact(cv, ci, out_w, graph.n, threshold=threshold)
-    start = jnp.take(graph.row_ptr, fi)
-    deg = jnp.take(graph.out_deg, fi)
-    # empty slots (fv == 0) push nothing: they get no lanes
-    budget = jnp.where(fv > 0, jnp.minimum(deg, cap), 0)
-    ends = jnp.cumsum(budget, axis=1).astype(jnp.int32)
-    # dangling mass seeds the running state (the one-shot path's last
-    # slot(s)); duplicate seed candidates dedup-merge on the first fold
-    dm = jnp.sum(jnp.where(deg == 0, fv, 0.0), axis=1)
-    dang_v, dang_i = dangling_seed_candidates(dm, sources, seed_weights, c=c)
-    run_v, run_i = frontier.topk_compact(dang_v, dang_i, out_w)
+    with jax.named_scope("ppr.push"):
+        q, k = fv.shape
+        m = graph.m
+        # seed-set queries emit S dangling candidates instead of 1 (see
+        # dangling_seed_candidates) — the one-shot width grows accordingly
+        s_width = 1 if seed_weights is None else int(seed_weights.shape[1])
+        if m == 0:  # all-dangling: S candidates per row, nothing to stream
+            cv, ci = sparse_push_candidates(
+                graph, fv, fi, sources, c=c, degree_cap=degree_cap,
+                seed_weights=seed_weights,
+            )
+            with jax.named_scope("ppr.fold"):
+                return frontier.compact(
+                    cv, ci, min(k_out, cv.shape[1]), graph.n,
+                    threshold=threshold,
+                )
+        cap = min(degree_cap, max(m, 1))
+        h, s = resolve_hub_splits(cap, hub_split_degree)
+        slot_w = s * h
+        out_w = min(k_out, k * slot_w + s_width)  # the one-shot path's width
+        lanes = stream_width if stream_width > 0 else max(4 * out_w, 16384)
+        if k * slot_w + s_width <= 2 * max(lanes, slot_w):  # narrow: one-shot
+            cv, ci = sparse_push_candidates(
+                graph, fv, fi, sources, c=c, degree_cap=degree_cap,
+                hub_split_degree=hub_split_degree, seed_weights=seed_weights,
+            )
+            with jax.named_scope("ppr.fold"):
+                return frontier.compact(
+                    cv, ci, out_w, graph.n, threshold=threshold)
+        start = jnp.take(graph.row_ptr, fi)
+        deg = jnp.take(graph.out_deg, fi)
+        # empty slots (fv == 0) push nothing: they get no lanes
+        budget = jnp.where(fv > 0, jnp.minimum(deg, cap), 0)
+        ends = jnp.cumsum(budget, axis=1).astype(jnp.int32)
+        # dangling mass seeds the running state (the one-shot path's last
+        # slot(s)); duplicate seed candidates dedup-merge on the first fold
+        dm = jnp.sum(jnp.where(deg == 0, fv, 0.0), axis=1)
+        dang_v, dang_i = dangling_seed_candidates(
+            dm, sources, seed_weights, c=c)
+        run_v, run_i = frontier.topk_compact(dang_v, dang_i, out_w)
 
-    def fold(b, carry):
-        pv, nb = gather_packed_edges(
-            fv, start, deg, ends, graph.col_idx, c=c, first=b * lanes,
-            lanes=lanes,
-        )
-        # mid-stream compaction truncates by rank only; the epsilon
-        # threshold applies once at the end, like the one-shot path
-        rv, ri, _ = frontier.fold_topk(*carry, pv, nb, out_w)
-        return rv, ri
+        def fold(b, carry):
+            pv, nb = gather_packed_edges(
+                fv, start, deg, ends, graph.col_idx, c=c, first=b * lanes,
+                lanes=lanes,
+            )
+            # mid-stream compaction truncates by rank only; the epsilon
+            # threshold applies once at the end, like the one-shot path
+            rv, ri, _ = frontier.fold_topk(*carry, pv, nb, out_w)
+            return rv, ri
 
-    blocks = (jnp.max(ends[:, -1]) + lanes - 1) // lanes
-    run_v, run_i = jax.lax.fori_loop(0, blocks, fold, (run_v, run_i))
-    if threshold > 0.0:
-        run_v = frontier.threshold_values(run_v, threshold)
-        run_v, run_i = frontier.topk_compact(run_v, run_i, out_w)
-    return frontier.SparseFrontier(
-        values=run_v, indices=run_i, k=out_w, n=graph.n
-    )
+        blocks = (jnp.max(ends[:, -1]) + lanes - 1) // lanes
+        run_v, run_i = jax.lax.fori_loop(0, blocks, fold, (run_v, run_i))
+        if threshold > 0.0:
+            run_v = frontier.threshold_values(run_v, threshold)
+            run_v, run_i = frontier.topk_compact(run_v, run_i, out_w)
+        return frontier.SparseFrontier(
+            values=run_v, indices=run_i, k=out_w, n=graph.n
+        )
 
 
 @functools.partial(
@@ -544,31 +557,34 @@ def _verd_iterate_sparse(
     degree_cap: int,
     hub_split_degree: int,
 ) -> Tuple[frontier.SparseFrontier, frontier.SparseFrontier]:
-    q = sources.shape[0]
-    if seed_weights is None:
-        f = frontier.from_sources(sources, graph.n)
-    else:
-        f = frontier.from_seed_sets(sources, seed_weights, graph.n)
-    s_vals, s_idxs = [], []
-    for _ in range(t):
-        s_vals.append(c * f.values)
-        s_idxs.append(f.indices)
-        f = sparse_push_compact(
-            graph, f.values, f.indices, sources, c=c, k_out=k,
-            degree_cap=degree_cap, hub_split_degree=hub_split_degree,
-            threshold=threshold, seed_weights=seed_weights,
-        )
-    if s_vals:
-        sv = jnp.concatenate(s_vals, axis=1)
-        si = jnp.concatenate(s_idxs, axis=1)
-        s = frontier.compact(sv, si, min(sv.shape[1], graph.n), graph.n)
-    else:  # t == 0: s is empty
-        s = frontier.SparseFrontier(
-            values=jnp.zeros((q, 1), jnp.float32),
-            indices=jnp.zeros((q, 1), jnp.int32),
-            k=1, n=graph.n,
-        )
-    return s, f
+    # each iteration's S <- S + c * F is part of the push, as in the
+    # dense verd_iterate
+    with jax.named_scope("ppr.push"):
+        q = sources.shape[0]
+        if seed_weights is None:
+            f = frontier.from_sources(sources, graph.n)
+        else:
+            f = frontier.from_seed_sets(sources, seed_weights, graph.n)
+        s_vals, s_idxs = [], []
+        for _ in range(t):
+            s_vals.append(c * f.values)
+            s_idxs.append(f.indices)
+            f = sparse_push_compact(
+                graph, f.values, f.indices, sources, c=c, k_out=k,
+                degree_cap=degree_cap, hub_split_degree=hub_split_degree,
+                threshold=threshold, seed_weights=seed_weights,
+            )
+        if s_vals:
+            sv = jnp.concatenate(s_vals, axis=1)
+            si = jnp.concatenate(s_idxs, axis=1)
+            s = frontier.compact(sv, si, min(sv.shape[1], graph.n), graph.n)
+        else:  # t == 0: s is empty
+            s = frontier.SparseFrontier(
+                values=jnp.zeros((q, 1), jnp.float32),
+                indices=jnp.zeros((q, 1), jnp.int32),
+                k=1, n=graph.n,
+            )
+        return s, f
 
 
 def verd_iterate_sparse(
@@ -652,21 +668,24 @@ def combine_with_index_sparse(
     out_k: Optional[int] = None,
 ) -> frontier.SparseFrontier:
     """Algorithm 4 line 10 on sparse state: contract ``f[Q, K]`` against only
-    the ``K`` touched index rows.
+    the ``K`` touched index rows (named scope ``ppr.combine``; the final
+    compaction under ``ppr.topk``).
 
     Gathers ``index`` rows at ``f.indices`` (``[Q, K, L]``), scales by the
     frontier mass, merges with the ``s`` entries, and compacts to ``out_k``
     (default: exact, no truncation).  Work is ``O(Q * K * L)`` — independent
     of ``n``.
     """
-    cand_v, cand_i = gather_combine_candidates(
-        s.values, s.indices, f.values, f.indices,
-        index.values, index.indices,
-    )
-    # compact pads narrow rows, so a requested out_k is always honored
-    if out_k is None:
-        out_k = min(cand_v.shape[1], index.n)
-    return frontier.compact(cand_v, cand_i, out_k, index.n)
+    with jax.named_scope("ppr.combine"):
+        cand_v, cand_i = gather_combine_candidates(
+            s.values, s.indices, f.values, f.indices,
+            index.values, index.indices,
+        )
+        # compact pads narrow rows, so a requested out_k is always honored
+        if out_k is None:
+            out_k = min(cand_v.shape[1], index.n)
+        with jax.named_scope("ppr.topk"):
+            return frontier.compact(cand_v, cand_i, out_k, index.n)
 
 
 def combine_with_index_scatter(
@@ -677,7 +696,8 @@ def combine_with_index_scatter(
     out_k: int,
     n_cols: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Final combine via a dense ``[Q, n]`` scatter-add + ``lax.top_k``.
+    """Final combine via a dense ``[Q, n]`` scatter-add + ``lax.top_k``
+    (named scope ``ppr.combine``; the selection under ``ppr.topk``).
 
     Same candidate set as :func:`combine_with_index_sparse`, but duplicates
     are merged by scattering into one zeroed ``[Q, n]`` scratch instead of
@@ -691,22 +711,24 @@ def combine_with_index_scatter(
     budget (``query.SCATTER_COMBINE_BUDGET_BYTES``) and keep the
     n-independent sparse combine beyond it.
     """
-    cand_v, cand_i = gather_combine_candidates(
-        s.values, s.indices, f.values, f.indices,
-        index.values, index.indices,
-    )
-    q = cand_v.shape[0]
-    n = index.n if n_cols is None else n_cols
-    dense = jnp.zeros((q, n), jnp.float32).at[
-        jnp.arange(q)[:, None], cand_i
-    ].add(cand_v, mode="drop")
-    vals, idx = jax.lax.top_k(dense, min(out_k, n))
-    idx = jnp.where(vals > 0, idx, 0).astype(jnp.int32)
-    if out_k > n:  # honor the requested width like frontier.compact does
-        pad = out_k - n
-        vals = jnp.pad(vals, ((0, 0), (0, pad)))
-        idx = jnp.pad(idx, ((0, 0), (0, pad)))
-    return vals, idx
+    with jax.named_scope("ppr.combine"):
+        cand_v, cand_i = gather_combine_candidates(
+            s.values, s.indices, f.values, f.indices,
+            index.values, index.indices,
+        )
+        q = cand_v.shape[0]
+        n = index.n if n_cols is None else n_cols
+        dense = jnp.zeros((q, n), jnp.float32).at[
+            jnp.arange(q)[:, None], cand_i
+        ].add(cand_v, mode="drop")
+        with jax.named_scope("ppr.topk"):
+            vals, idx = jax.lax.top_k(dense, min(out_k, n))
+            idx = jnp.where(vals > 0, idx, 0).astype(jnp.int32)
+            if out_k > n:  # honor the requested width like compact does
+                pad = out_k - n
+                vals = jnp.pad(vals, ((0, 0), (0, pad)))
+                idx = jnp.pad(idx, ((0, 0), (0, pad)))
+            return vals, idx
 
 
 def verd_query_sparse(
@@ -734,7 +756,8 @@ def verd_query_sparse(
     )
     if index is None:
         if out_k is not None:
-            return frontier.compact(s.values, s.indices, out_k, graph.n)
+            with jax.named_scope("ppr.topk"):
+                return frontier.compact(s.values, s.indices, out_k, graph.n)
         return s
     return combine_with_index_sparse(s, f, index, out_k=out_k)
 

@@ -587,19 +587,25 @@ def simulate_walks_sparse(
     src2d = src32[:, None]
 
     launched0 = min(r, schedule[0])
-    cursors = jnp.broadcast_to(src2d, (rows, schedule[0])).astype(jnp.int32)
-    alive = jnp.broadcast_to(
-        jnp.arange(schedule[0], dtype=jnp.int32)[None, :] < launched0,
-        (rows, schedule[0]),
-    )
-    quota = jnp.full((rows,), r - launched0, jnp.int32)
-    fp = _EventSketch(rows, max(l, 1), fold_width, enabled=track_fp)
-    ep = _EventSketch(rows, max(ep_l, 1), fold_width, enabled=track_ep)
-    moves = jnp.zeros((rows,), jnp.float32)
-    walks_done = jnp.zeros((rows,), jnp.float32)
-    truncated = jnp.zeros((rows,), jnp.float32)
     track_touch = touch_bits > 0
-    touch = jnp.zeros((rows, touch_bits), bool) if track_touch else None
+    # named scopes: walk state and steps under ppr.walk.step, slot
+    # compaction under ppr.slot_compact, event sketches under
+    # ppr.sketch_fold (each fold inside it under ppr.fold)
+    with jax.named_scope("ppr.walk.step"):
+        cursors = jnp.broadcast_to(
+            src2d, (rows, schedule[0])).astype(jnp.int32)
+        alive = jnp.broadcast_to(
+            jnp.arange(schedule[0], dtype=jnp.int32)[None, :] < launched0,
+            (rows, schedule[0]),
+        )
+        quota = jnp.full((rows,), r - launched0, jnp.int32)
+        moves = jnp.zeros((rows,), jnp.float32)
+        walks_done = jnp.zeros((rows,), jnp.float32)
+        truncated = jnp.zeros((rows,), jnp.float32)
+    with jax.named_scope("ppr.sketch_fold"):
+        fp = _EventSketch(rows, max(l, 1), fold_width, enabled=track_fp)
+        ep = _EventSketch(rows, max(ep_l, 1), fold_width, enabled=track_ep)
+        touch = jnp.zeros((rows, touch_bits), bool) if track_touch else None
     _touch_rows = jnp.arange(rows, dtype=jnp.int32)[:, None, None]
 
     def record_touch(tch, ev_i, ev_live):
@@ -657,48 +663,54 @@ def simulate_walks_sparse(
     t0 = 0
     for w in schedule:
         if w < cursors.shape[1]:
-            cursors, alive, ov_w, ov_i = _compact_slots(cursors, alive, w)
-            # overflow walks: truncate to endpoint (schedule tail event)
-            n_over = jnp.sum(ov_w, axis=1)
-            walks_done = walks_done + n_over
-            truncated = truncated + n_over
-            ep.add(ov_w, ov_i)
+            with jax.named_scope("ppr.slot_compact"):
+                cursors, alive, ov_w, ov_i = _compact_slots(
+                    cursors, alive, w)
+                # overflow walks: truncate to endpoint (schedule tail event)
+                n_over = jnp.sum(ov_w, axis=1)
+                walks_done = walks_done + n_over
+                truncated = truncated + n_over
+            with jax.named_scope("ppr.sketch_fold"):
+                ep.add(ov_w, ov_i)
         # the last round may be ragged: never run past the step budget
         steps = min(compact_every, total_steps - t0)
-        u_move, u_term = round_uniforms(t0, steps, w)
-        (cursors, alive, quota, moves, walks_done), (vis_w, vis_i, term_w) = (
-            jax.lax.scan(
+        with jax.named_scope("ppr.walk.step"):
+            u_move, u_term = round_uniforms(t0, steps, w)
+            carry, (vis_w, vis_i, term_w) = jax.lax.scan(
                 step_body, (cursors, alive, quota, moves, walks_done),
                 (u_term, u_move),
             )
-        )
-        fp.add(per_row(vis_w), per_row(vis_i))
-        ep.add(per_row(term_w), per_row(vis_i))
-        if track_touch:
-            touch = record_touch(touch, per_row(vis_i), per_row(vis_w) > 0)
+            cursors, alive, quota, moves, walks_done = carry
+        with jax.named_scope("ppr.sketch_fold"):
+            fp.add(per_row(vis_w), per_row(vis_i))
+            ep.add(per_row(term_w), per_row(vis_i))
+            if track_touch:
+                touch = record_touch(
+                    touch, per_row(vis_i), per_row(vis_w) > 0)
         t0 += steps
 
-    # step-budget cap: survivors' current position is the endpoint (the
-    # same truncation as the legacy engine; tail mass ~ (1-c)^max_steps)
-    af = alive.astype(jnp.float32)
-    n_trunc = jnp.sum(af, axis=1)
-    walks_done = walks_done + n_trunc
-    truncated = truncated + n_trunc
-    ep.add(af, jnp.where(alive, cursors, 0))
-    if respawn:
-        # quota the pass never got to launch: flush as length-1 walks (one
-        # counted position at the source) so walks == R stays exact; a
-        # slack-tail event, ledgered like any other truncation
-        q_rem = quota.astype(jnp.float32)
-        moves = moves + q_rem
-        walks_done = walks_done + q_rem
-        truncated = truncated + q_rem
-        fp.add(q_rem[:, None], src2d)
-        ep.add(q_rem[:, None], src2d)
-        if track_touch:
-            touch = record_touch(touch, src2d, q_rem[:, None] > 0)
-    fp.flush()
-    ep.flush()
+    with jax.named_scope("ppr.sketch_fold"):
+        # step-budget cap: survivors' current position is the endpoint (the
+        # same truncation as the legacy engine; tail mass ~ (1-c)^max_steps)
+        af = alive.astype(jnp.float32)
+        n_trunc = jnp.sum(af, axis=1)
+        walks_done = walks_done + n_trunc
+        truncated = truncated + n_trunc
+        ep.add(af, jnp.where(alive, cursors, 0))
+        if respawn:
+            # quota the pass never got to launch: flush as length-1 walks
+            # (one counted position at the source) so walks == R stays
+            # exact; a slack-tail event, ledgered like any other truncation
+            q_rem = quota.astype(jnp.float32)
+            moves = moves + q_rem
+            walks_done = walks_done + q_rem
+            truncated = truncated + q_rem
+            fp.add(q_rem[:, None], src2d)
+            ep.add(q_rem[:, None], src2d)
+            if track_touch:
+                touch = record_touch(touch, src2d, q_rem[:, None] > 0)
+        fp.flush()
+        ep.flush()
     return SparseWalkCounts(
         fp=frontier_mod.SparseFrontier(
             values=fp.values, indices=fp.indices, k=max(l, 1), n=n

@@ -16,10 +16,12 @@ have finished — see ``serving/pipeline.py`` and docs/serving_path.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import annotate_function
 
 from repro.core.graph import Graph
 from repro.core.index import PPRIndex
@@ -100,7 +102,7 @@ class PPRService:
         )
         self.stats: Dict[str, float] = dict(
             served=0, batches=0, total_latency=0.0, max_latency=0.0,
-            pad_rows=0, first_batch_service_s=0.0, cache_served=0,
+            pad_rows=0, cache_served=0,
             updates_applied=0, rows_repaired=0, cache_stale_drops=0,
             shed=0, update_rollbacks=0,
         )
@@ -144,6 +146,7 @@ class PPRService:
         return cls(graph, None, cfg, clock=clock, maintainer=m)
 
     # -- client API ----------------------------------------------------------
+    @functools.partial(annotate_function, name="ppr.submit")
     def submit(self, vertex: Optional[int] = None, tier: str = "interactive",
                arrival: Optional[float] = None,
                seeds: Optional[Sequence[int]] = None,
@@ -311,6 +314,8 @@ class PPRService:
         if more or (drain and self.pipeline.in_flight):
             completed.extend(more)
             completed.extend(self.pipeline.harvest(drain=drain))
+        if not completed:  # absorb (and its span) only what came back
+            return cached
         return cached + self._absorb(completed)
 
     # -- bookkeeping ---------------------------------------------------------
@@ -351,16 +356,10 @@ class PPRService:
         self._pending_rejected.clear()
         return out
 
+    @functools.partial(annotate_function, name="ppr.absorb")
     def _absorb(self, completed: List[CompletedBatch]) -> List[Answer]:
         out: List[Answer] = []
         for batch in completed:
-            if not self.stats["batches"]:
-                # satellite fix: record first-batch service time (dominated
-                # by jit compilation on a cold service) so load harnesses
-                # can report wall_s_excl_first_batch alongside raw wall
-                self.stats["first_batch_service_s"] = (
-                    batch.completed_at - batch.dispatched_at
-                )
             self.stats["pad_rows"] += batch.padded - len(batch.requests)
             self.stats["batches"] += 1
             for i, r in enumerate(batch.requests):

@@ -140,13 +140,8 @@ def run_open_loop(
     s = service.snapshot_stats()
     lat = [a.latency_s for a in answers]
     s["wall_s"] = wall
-    # satellite fix: a cold service's first batch is dominated by jit
-    # compilation; report throughput with and without it so benchmark
-    # trajectories aren't dominated by compile time
-    s["wall_s_excl_first_batch"] = max(wall - s["first_batch_service_s"], 1e-9)
     s["offered_qps"] = float(qps) if qps else 0.0
     s["qps"] = len(answers) / max(wall, 1e-9)
-    s["qps_excl_first_batch"] = len(answers) / s["wall_s_excl_first_batch"]
     s["latency_p50"] = _percentile(lat, 50)
     s["latency_p99"] = _percentile(lat, 99)
     return answers, s

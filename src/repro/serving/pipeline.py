@@ -37,6 +37,7 @@ from typing import Callable, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.batching import Request, RequestBuffer
 
@@ -165,9 +166,12 @@ class ServingPipeline:
         self.epoch_fn = epoch_fn
         self.queue = CompletionQueue(cfg.depth)
         self._seq = 0
+        # buffer_wait_s: summed over dispatched requests, the time from
+        # arrival to the dispatch that drained it (on ``clock``)
         self.stats: Dict[str, float] = dict(
             dispatched=0, harvested=0, queue_full_stalls=0, in_flight_peak=0,
             buffers_allocated=0, buffers_reused=0, stalled=0,
+            buffer_wait_s=0.0,
         )
         # padded batch width -> count; the benchmark's batch-size histogram
         self.batch_hist: Dict[int, int] = collections.Counter()
@@ -245,6 +249,23 @@ class ServingPipeline:
             self.stats["queue_full_stalls"] += 1
             out.append(self._complete(self.queue.pop(block=True)))
         requests, padded = self.buffer.drain()
+        with TraceAnnotation("ppr.dispatch", seq=self._seq,
+                             rows=len(requests), padded=padded):
+            now = self.clock()
+            self.stats["buffer_wait_s"] += sum(now - r.arrival
+                                               for r in requests)
+            ticket = self._launch(requests, padded)
+        self._seq += 1
+        self.queue.push(ticket)
+        self.stats["dispatched"] += 1
+        self.stats["in_flight_peak"] = max(
+            self.stats["in_flight_peak"], len(self.queue)
+        )
+        self.batch_hist[padded] += 1
+        return out
+
+    def _launch(self, requests: List[Request], padded: int) -> PendingBatch:
+        """Launch one drained batch on the engine; returns its ticket."""
         verts, weights = self._batch_arrays(requests, padded)
         if self.cfg.dispatch == "legacy":
             if weights is None:
@@ -270,18 +291,10 @@ class ServingPipeline:
             vals, idx = self.engine.query_topk_async(
                 verts, key=self.engine.dispatch_key(self._seq), **kwargs
             )
-        ticket = PendingBatch(
+        return PendingBatch(
             self._seq, requests, padded, vals, idx, self.clock(),
             epoch=self.epoch_fn() if self.epoch_fn is not None else 0,
         )
-        self._seq += 1
-        self.queue.push(ticket)
-        self.stats["dispatched"] += 1
-        self.stats["in_flight_peak"] = max(
-            self.stats["in_flight_peak"], len(self.queue)
-        )
-        self.batch_hist[padded] += 1
-        return out
 
     # -- completion phase ----------------------------------------------------
     def harvest(self, drain: bool = False) -> List[CompletedBatch]:
@@ -330,28 +343,29 @@ class ServingPipeline:
         return out
 
     def _complete(self, ticket: PendingBatch) -> CompletedBatch:
-        n_real = len(ticket.requests)
-        # pad rows never reach answers or stats: slice them off on device so
-        # only the real rows' top-k is materialized on the host
-        # contract: allow(host-sync): post-is_ready harvest — the ticket's
-        # arrays are already resident when _complete runs, so these copies
-        # never stall the dispatch thread
-        vals = np.asarray(ticket.values[:n_real])
-        # contract: allow(host-sync): post-is_ready harvest (see above)
-        idx = np.asarray(ticket.indices[:n_real])
-        self.stats["harvested"] += 1
-        if (
-            self.cfg.reuse_buffers
-            and self.cfg.dispatch == "fused"
-            and hasattr(ticket.values, "is_ready")  # jax arrays only
-        ):
-            # the host copies above are independent of the device buffers,
-            # so the full-width result arrays go back in the ring to be
-            # donated to the next dispatch of this padded width
-            self._ring.setdefault(
-                ticket.padded, collections.deque()
-            ).append((ticket.values, ticket.indices))
-        return CompletedBatch(
-            ticket.seq, ticket.requests, ticket.padded, vals, idx,
-            ticket.dispatched_at, self.clock(), epoch=ticket.epoch,
-        )
+        with TraceAnnotation("ppr.harvest", seq=ticket.seq):
+            n_real = len(ticket.requests)
+            # pad rows never reach answers or stats: slice them off on
+            # device so only the real rows' top-k is materialized on the host
+            # contract: allow(host-sync): post-is_ready harvest — the
+            # ticket's arrays are already resident when _complete runs, so
+            # these copies never stall the dispatch thread
+            vals = np.asarray(ticket.values[:n_real])
+            # contract: allow(host-sync): post-is_ready harvest (see above)
+            idx = np.asarray(ticket.indices[:n_real])
+            self.stats["harvested"] += 1
+            if (
+                self.cfg.reuse_buffers
+                and self.cfg.dispatch == "fused"
+                and hasattr(ticket.values, "is_ready")  # jax arrays only
+            ):
+                # the host copies above are independent of the device
+                # buffers, so the full-width result arrays go back in the
+                # ring to be donated to the next dispatch of this width
+                self._ring.setdefault(
+                    ticket.padded, collections.deque()
+                ).append((ticket.values, ticket.indices))
+            return CompletedBatch(
+                ticket.seq, ticket.requests, ticket.padded, vals, idx,
+                ticket.dispatched_at, self.clock(), epoch=ticket.epoch,
+            )
