@@ -401,14 +401,54 @@ def sparse_push_candidates(
         )
 
 
-def gather_packed_edges(
+def packed_lane_slots(
+    ends: jax.Array, first: jax.Array, lanes: int
+) -> jax.Array:
+    """Frontier slot of each lane ``e`` in ``[first, first + lanes)``.
+
+    ``ends`` ``[Q, K]`` holds each row's inclusive cumulative slot budgets;
+    lane ``e`` belongs to the first slot whose end lies past it, i.e.
+    ``searchsorted(ends[q], e, side="right")`` clamped to ``K - 1``.  The
+    map is monotone along the lanes and steps only at the slot ends, so it
+    is the count of ends at or before ``first`` plus a prefix sum of the
+    ends that fall inside the block: one scatter of ``Q * K`` boundaries and
+    one ``[Q, lanes]`` prefix sum instead of a bisection per lane.
+    """
+    q, k = ends.shape
+    base = jnp.sum(ends <= first, axis=1, dtype=jnp.int32)          # [Q]
+    pos = ends - first
+    # an end at lane p > 0 starts the next slot there; ends at or before
+    # `first` are in `base`, and ends past the block are dropped
+    pos = jnp.where(pos > 0, pos, lanes)
+    steps = jnp.zeros((q, lanes), jnp.int32).at[
+        jnp.arange(q)[:, None], pos].add(1, mode="drop")
+    return jnp.minimum(base[:, None] + jnp.cumsum(steps, axis=1), k - 1)
+
+
+def packed_slot_tables(
     fv: jax.Array,
     start: jax.Array,
     deg: jax.Array,
     ends: jax.Array,
-    col_idx: jax.Array,
     *,
     c: float,
+) -> Tuple[jax.Array, jax.Array]:
+    """Per-slot ``(d, w)`` for :func:`gather_packed_edges`, built once per
+    push: lane ``e`` of slot ``j`` reads CSR edge ``e + d[j]`` (``d = start
+    - (ends - budget)``) and pushes weight ``w[j] = (1 - c) * fv / deg``."""
+    with jax.named_scope("ppr.push.gather"):
+        before = jnp.pad(ends[:, :-1], ((0, 0), (1, 0)))
+        d = (start - before).astype(jnp.int32)
+        inv = 1.0 / jnp.maximum(deg.astype(jnp.float32), 1.0)
+        return d, (1.0 - c) * fv * inv
+
+
+def gather_packed_edges(
+    d: jax.Array,
+    w: jax.Array,
+    ends: jax.Array,
+    col_idx: jax.Array,
+    *,
     first: jax.Array,
     lanes: int,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -416,31 +456,22 @@ def gather_packed_edges(
 
     Each row's pushed edges are numbered slot after slot: slot ``j`` owns
     ``[ends[j] - budget_j, ends[j])`` (``ends`` is the inclusive cumulative
-    sum of the per-slot edge budgets).  Lane ``e`` finds its slot by binary
-    search and reads edge ``e - (ends[slot] - budget_slot)`` of that slot's
-    CSR row, so no lane is spent on a slot's unused ``degree_cap`` padding.
-    Returns ``(push_v, nbrs)`` of width ``lanes``: weights ``(1 - c) * fv /
-    deg``, empty lanes ``(0.0, 0)`` — the same candidates, in another
-    grouping, as :func:`gather_push_edges`.
+    sum of the per-slot edge budgets).  Lane ``e`` finds its slot by a
+    prefix sum over the slot boundaries (:func:`packed_lane_slots`) and
+    reads the slot's two tables from :func:`packed_slot_tables`: CSR edge
+    ``e + d[slot]`` and weight ``w[slot]``, so no lane is spent on a slot's
+    unused ``degree_cap`` padding.  Returns ``(push_v, nbrs)`` of width
+    ``lanes``: weights ``(1 - c) * fv / deg``, empty lanes ``(0.0, 0)`` —
+    the same candidates, in another grouping, as :func:`gather_push_edges`.
     """
-    q, k = fv.shape
     with jax.named_scope("ppr.push.gather"):
         e = first + jnp.arange(lanes, dtype=jnp.int32)
-        slot = jax.vmap(
-            lambda row: jnp.searchsorted(row, e, side="right")
-        )(ends).astype(jnp.int32)
+        slot = packed_lane_slots(ends, first, lanes)
         valid = e[None, :] < ends[:, -1:]
-        slot = jnp.minimum(slot, k - 1)
-        before = jnp.where(
-            slot > 0,
-            jnp.take_along_axis(ends, jnp.maximum(slot - 1, 0), axis=1), 0,
-        )
         at = lambda x: jnp.take_along_axis(x, slot, axis=1)
         m = col_idx.shape[0]
-        nbrs = jnp.take(
-            col_idx, jnp.clip(at(start) + e[None, :] - before, 0, m - 1))
-        inv = 1.0 / jnp.maximum(at(deg).astype(jnp.float32), 1.0)
-        push_v = jnp.where(valid, (1.0 - c) * at(fv) * inv, 0.0)
+        nbrs = jnp.take(col_idx, jnp.clip(e[None, :] + at(d), 0, m - 1))
+        push_v = jnp.where(valid, at(w), 0.0)
         return push_v, jnp.where(valid, nbrs, 0)
 
 
@@ -518,12 +549,11 @@ def sparse_push_compact(
         dang_v, dang_i = dangling_seed_candidates(
             dm, sources, seed_weights, c=c)
         run_v, run_i = frontier.topk_compact(dang_v, dang_i, out_w)
+        d, w = packed_slot_tables(fv, start, deg, ends, c=c)
 
         def fold(b, carry):
             pv, nb = gather_packed_edges(
-                fv, start, deg, ends, graph.col_idx, c=c, first=b * lanes,
-                lanes=lanes,
-            )
+                d, w, ends, graph.col_idx, first=b * lanes, lanes=lanes)
             # mid-stream compaction truncates by rank only; the epsilon
             # threshold applies once at the end, like the one-shot path
             rv, ri, _ = frontier.fold_topk(*carry, pv, nb, out_w)

@@ -362,6 +362,119 @@ def test_hub_graph_sparse_query_streams_bounded(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("budgets, first, lanes", [
+    ([[0, 2, 0, 0, 3, 0]], 0, 8),                      # zero budgets
+    ([[3, 4, 2]], 2, 4),                               # block before a boundary
+    ([[3, 4, 2]], 3, 4),                               # block on a boundary
+    ([[3, 4, 2]], 4, 4),                               # block after a boundary
+    ([[3, 4, 2]], 12, 4),                              # past the row's total
+    ([[3, 0, 4, 2]], 3, 1),                            # one lane
+    ([[3, 4, 2], [1, 1, 0]], 0, 64),                   # lanes over every row
+    ([[5], [0]], 2, 6),                                # one slot
+    ([[1, 0, 7, 2], [0, 0, 0, 1], [4, 4, 4, 4]], 3, 5),  # unequal totals
+], ids=["zero-budgets", "before-boundary", "on-boundary", "after-boundary",
+        "past-total", "one-lane", "wide-lanes", "one-slot", "unequal-rows"])
+def test_packed_lane_slots_equal_searchsorted(budgets, first, lanes):
+    """The boundary prefix sum gives each lane the slot a right-sided
+    binary search over the cumulative budgets gives, clamped to K - 1."""
+    ends = np.cumsum(np.asarray(budgets), axis=1).astype(np.int32)
+    got = verd_mod.packed_lane_slots(jnp.asarray(ends), jnp.int32(first),
+                                     lanes)
+    e = first + np.arange(lanes)
+    want = np.stack([np.searchsorted(r, e, side="right") for r in ends])
+    np.testing.assert_array_equal(
+        np.asarray(got), np.minimum(want, ends.shape[1] - 1))
+
+
+def _bisection_gather_packed_edges(fv, start, deg, ends, col_idx, *, c,
+                                   first, lanes):
+    """The streamed push's lane gather as a per-lane binary search over the
+    cumulative budgets, reading each slot's fields at every lane: the
+    reference :func:`verd.gather_packed_edges` must equal bit for bit."""
+    k = fv.shape[1]
+    e = first + jnp.arange(lanes, dtype=jnp.int32)
+    slot = jax.vmap(
+        lambda row: jnp.searchsorted(row, e, side="right"))(ends)
+    slot = jnp.minimum(slot.astype(jnp.int32), k - 1)
+    valid = e[None, :] < ends[:, -1:]
+    before = jnp.where(
+        slot > 0, jnp.take_along_axis(ends, jnp.maximum(slot - 1, 0), axis=1),
+        0)
+    at = lambda x: jnp.take_along_axis(x, slot, axis=1)
+    m = col_idx.shape[0]
+    nbrs = jnp.take(
+        col_idx, jnp.clip(at(start) + e[None, :] - before, 0, m - 1))
+    inv = 1.0 / jnp.maximum(at(deg).astype(jnp.float32), 1.0)
+    push_v = jnp.where(valid, (1.0 - c) * at(fv) * inv, 0.0)
+    return push_v, jnp.where(valid, nbrs, 0)
+
+
+def _assert_bitwise(got, want):
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g.view(np.uint32), w.view(np.uint32))
+
+
+@pytest.mark.parametrize("hub_split_degree", [0, 8])
+@pytest.mark.parametrize("name", ["rmat", "star"])
+def test_packed_gather_bitwise_equals_bisection(monkeypatch, name,
+                                                hub_split_degree):
+    """Prefix-sum slots and per-slot tables give the bisection's candidates
+    bit for bit, block by block and through the whole streamed push, with
+    dangling and empty slots; and no bisection loop is left to trace."""
+    from repro.analysis.jaxpr import iter_eqns
+
+    g = synthetic.rmat(7, 6.0, seed=3) if name == "rmat" else synthetic.star(
+        96)
+    c, q, k, lanes = 0.15, 4, 12, 7
+    rng = np.random.default_rng(12)
+    deg_h = np.asarray(g.out_deg)
+    fi_h = rng.integers(0, g.n, (q, k))
+    fi_h[:, 0] = np.argmax(deg_h)                  # the hub in every row
+    if name == "rmat":
+        fi_h[:, 1] = np.flatnonzero(deg_h == 0)[0]  # a dangling slot
+    fv_h = rng.random((q, k)).astype(np.float32)
+    fv_h[rng.random((q, k)) < 0.25] = 0.0          # empty slots
+    fv_h[0, :] = 0.0                               # a row with no edges
+    fv, fi = jnp.asarray(fv_h), jnp.asarray(fi_h, jnp.int32)
+    srcs = jnp.asarray(rng.integers(0, g.n, q), jnp.int32)
+    cap = verd_mod.resolve_degree_cap(g)
+    start = jnp.take(g.row_ptr, fi)
+    deg = jnp.take(g.out_deg, fi)
+    ends = jnp.cumsum(jnp.where(fv > 0, jnp.minimum(deg, cap), 0),
+                      axis=1).astype(jnp.int32)
+    d, w = verd_mod.packed_slot_tables(fv, start, deg, ends, c=c)
+    blocks = -(-int(jnp.max(ends[:, -1])) // lanes)
+    assert blocks >= 3
+    for b in range(blocks + 1):
+        first = jnp.int32(b * lanes)
+        _assert_bitwise(
+            verd_mod.gather_packed_edges(d, w, ends, g.col_idx, first=first,
+                                         lanes=lanes),
+            _bisection_gather_packed_edges(fv, start, deg, ends, g.col_idx,
+                                           c=c, first=first, lanes=lanes))
+
+    kw = dict(c=c, degree_cap=cap, k_out=24, stream_width=lanes,
+              hub_split_degree=hub_split_degree)
+    new = verd_mod.sparse_push_compact(g, fv, fi, srcs, **kw)
+    monkeypatch.setattr(
+        verd_mod, "gather_packed_edges",
+        lambda d_, w_, ends_, col_idx, *, first, lanes:
+        _bisection_gather_packed_edges(fv, start, deg, ends_, col_idx, c=c,
+                                       first=first, lanes=lanes))
+    old = verd_mod.sparse_push_compact(g, fv, fi, srcs, **kw)
+    _assert_bitwise((new.values, new.indices), (old.values, old.indices))
+    monkeypatch.undo()
+
+    jaxpr = jax.make_jaxpr(
+        lambda *a: verd_mod.gather_packed_edges(*a[:4], first=a[4],
+                                                lanes=lanes))(
+        d, w, ends, g.col_idx, jnp.int32(0))
+    prims = {eqn.primitive.name for eqn in iter_eqns(jaxpr)}
+    assert not prims & {"while", "scan"}, prims   # searchsorted's loop
+
+
 def test_engine_auto_k_covers_expected_support():
     """Auto K must scale with mean_degree**t so auto-routed sparse answers
     aren't silently truncated below the typical frontier support."""
